@@ -4,8 +4,10 @@ The paper: on the 5 MB-L1 edge device in fp16, MAS handles ~1 M tokens
 (two row buffers must coexist: P_i plus C_{i+1} or P_{i-1}) while FLAT
 handles ~2 M (one row buffer). We sweep N and report the largest
 feasible length for each dataflow under the §4.3 capacity rules, plus
-the TPU-side analogue from core.policy (where the same 2-buffer trade
-decides when the paper's dataflow yields to the online-softmax kernel).
+the TPU-side analogue from core.policy: the N at which the policy leaves
+K/V-resident MAS for streamed MAS, and the N at which the paper's
+dataflow yields to the online-softmax kernel, at the VMEM budget the MAS
+kernel is compiled with (E=128, bf16).
 """
 
 from __future__ import annotations
@@ -14,7 +16,7 @@ from repro.sim import EDGE_HW
 from repro.sim.schedules import Tiling, build_schedule
 from repro.sim.workload import AttentionWorkload
 
-from repro.core.policy import choose_attention_method
+from repro.core.policy import DEFAULT_VMEM_BUDGET, choose_attention_method
 
 
 def _feasible(method: str, n: int, hw=EDGE_HW, emb: int = 64,
@@ -41,25 +43,36 @@ def max_len(method: str, hw=EDGE_HW) -> int:
     return lo
 
 
+def first_n(left: tuple[str, ...], budget: int = DEFAULT_VMEM_BUDGET,
+            step: int = 512) -> int:
+    """Smallest N (a multiple of ``step``) whose policy decision is not in
+    ``left``; the decisions move in one direction as N grows."""
+
+    def past(n):
+        d = choose_attention_method(n_kv=n, e=128, itemsize=2,
+                                    vmem_budget=budget)
+        return d.method not in left
+
+    lo, hi = step, step
+    while not past(hi):
+        lo, hi = hi, hi * 2
+    while hi - lo > step:
+        mid = (lo + hi) // 2 // step * step
+        lo, hi = (lo, mid) if past(mid) else (mid, hi)
+    return hi
+
+
 def run():
     mas_n = max_len("mas")
     flat_n = max_len("flat")
-    # TPU analogue: where does the paper's dataflow stop fitting VMEM?
-    tpu_mas_limit = None
-    n = 1 << 12
-    while n <= 1 << 24:
-        d = choose_attention_method(n_kv=n, e=128, itemsize=2,
-                                    vmem_budget=16 * 2**20)
-        if d.method == "flash":
-            tpu_mas_limit = n
-            break
-        n <<= 1
     return {
         "mas_max_seq": mas_n,
         "flat_max_seq": flat_n,
         "ratio_flat_over_mas": flat_n / mas_n,
         "paper": {"mas": 1_000_000, "flat": 2_000_000, "ratio": 2.0},
-        "tpu16mb_mas_to_flash_at": tpu_mas_limit,
+        "tpu_vmem_budget": DEFAULT_VMEM_BUDGET,
+        "tpu_resident_to_streamed_at": first_n(("mas_resident",)),
+        "tpu_mas_to_flash_at": first_n(("mas_resident", "mas_streamed")),
     }
 
 
@@ -69,6 +82,10 @@ def main(emit):
     emit("seq_limit/flat_max", 0.0, f"N={r['flat_max_seq']:,} (paper ~2M)")
     emit("seq_limit/ratio", 0.0,
          f"flat/mas={r['ratio_flat_over_mas']:.2f} (paper 2.0)")
+    mib = r["tpu_vmem_budget"] // 2**20
+    emit("seq_limit/tpu_resident_handoff", 0.0,
+         f"resident->streamed at N={r['tpu_resident_to_streamed_at']:,} "
+         f"({mib}MiB VMEM)")
     emit("seq_limit/tpu_policy_handoff", 0.0,
-         f"MAS->flash at N={r['tpu16mb_mas_to_flash_at']:,} (16MiB VMEM)")
+         f"MAS->flash at N={r['tpu_mas_to_flash_at']:,} ({mib}MiB VMEM)")
     return r

@@ -22,9 +22,10 @@ from __future__ import annotations
 
 import dataclasses
 
-# Conservative usable-VMEM default for one core's kernel working set.
-# v5e exposes ~128 MiB VMEM per core; Mosaic needs headroom for
-# double-buffering and spills, so budget half by default.
+# Usable VMEM for one kernel's working set. v5e has 128 MiB of VMEM per
+# core; the MAS kernel asks the compiler for exactly this much scoped VMEM
+# (mas_attention_flat's ``vmem_limit_bytes``), so the budget planned here
+# and the limit compiled against are one number.
 DEFAULT_VMEM_BUDGET = 64 * 2**20
 
 
@@ -60,21 +61,39 @@ def _bytes(n_elems: int, itemsize: int) -> int:
     return n_elems * itemsize
 
 
+def sublane_rows(itemsize: int) -> int:
+    # Smallest Q block the kernels tile: fp32 -> 8, bf16 -> 16, int8 -> 32
+    # rows (ops.attention rounds blk_q up to this).
+    return {4: 8, 2: 16, 1: 32}.get(itemsize, 8)
+
+
 def mas_vmem_bytes(
     blk_q: int, blk_kv: int, n: int, e: int, itemsize: int,
     kv_resident: bool,
 ) -> int:
-    """VMEM working set of the MAS kernel (scratch + pipeline buffers)."""
-    s_row = _bytes(blk_q * n, 4)  # fp32 full score row (Alg. 3)
-    q_blk = 2 * _bytes(blk_q * e, itemsize)  # double-buffered
-    o_blk = 2 * _bytes(blk_q * e, itemsize)
+    """VMEM the MAS kernel needs, counted as Mosaic allocates it.
+
+    * the fp32 (blk_q, N) score row buffer (Alg. 3), plus one fp32 row
+      temporary of the same size that the row softmax holds beside it;
+    * every input and output block twice: Pallas double-buffers each
+      BlockSpec, resident K/V included;
+    * two fp32 (blk_q, blk_kv) score-tile temporaries (matmul result and
+      its mask).
+
+    N is padded to whole ``blk_kv`` tiles, as ops.attention pads K/V.
+    tests/test_tpu_compile.py checks the count against the v5e compiler.
+    """
+    n = -(-n // blk_kv) * blk_kv
+    rows = 2 * _bytes(blk_q * n, 4)
+    tiles = 2 * _bytes(blk_q * blk_kv, 4)
+    q_o = 2 * 2 * _bytes(blk_q * e, itemsize)
     if kv_resident:
-        kv = 2 * _bytes(n * e, itemsize)  # K + V pinned
+        kv = 2 * 2 * _bytes(n * e, itemsize)  # K + V pinned, double-buffered
         acc = 0  # accumulates via fori carry (vregs)
     else:
-        kv = 4 * _bytes(blk_kv * e, itemsize)  # K,V tiles double-buffered
+        kv = 2 * 2 * _bytes(blk_kv * e, itemsize)  # K, V tiles
         acc = _bytes(blk_q * e, 4)
-    return s_row + q_blk + o_blk + kv + acc
+    return rows + tiles + q_o + kv + acc
 
 
 def flash_vmem_bytes(blk_q: int, blk_kv: int, e: int, itemsize: int) -> int:
@@ -132,7 +151,7 @@ def choose_attention_method(
     # Shrink blk_q before giving up on the paper's dataflow — the paper
     # shrinks N_Q the same way for long sequences (§5.6).
     bq = blk_q
-    while bq > 8:
+    while bq > sublane_rows(itemsize):
         bq //= 2
         streamed = mas_vmem_bytes(bq, blk_kv, n_kv, e, itemsize, False)
         if streamed <= vmem_budget:
@@ -143,8 +162,9 @@ def choose_attention_method(
 
     if prefer == "mas":
         raise ValueError(
-            f"MAS dataflow infeasible: one fp32 score row of n_kv={n_kv} "
-            f"needs {8 * n_kv * 4} B > budget {vmem_budget} B (paper §5.6)"
+            f"MAS dataflow infeasible: the fp32 score rows of n_kv={n_kv} "
+            f"need {mas_vmem_bytes(bq, blk_kv, n_kv, e, itemsize, False)} B "
+            f"> budget {vmem_budget} B (paper §5.6)"
         )
     return PolicyDecision(
         "flash", TilingConfig(blk_q, blk_kv, False),

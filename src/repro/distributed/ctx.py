@@ -24,10 +24,12 @@ from jax.sharding import PartitionSpec as P
 
 _state = threading.local()
 
-# pvary marks a value as device-varying inside shard_map (jax >= 0.6
-# varying-ness types); on older jax there is no varying-ness tracking
-# and identity is correct. Shared by the shard_map-based collectives.
-pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
+
+def pvary(x, axes: tuple[str, ...]):
+    """Mark ``x`` device-varying over ``axes`` inside shard_map, so a
+    fresh constant and a per-device value give loop carries one type.
+    Shared by the shard_map-based collectives."""
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def _axes() -> dict[str, int] | None:

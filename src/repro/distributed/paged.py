@@ -38,7 +38,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed.ctx import pvary as _pvary
@@ -95,8 +94,8 @@ def ring_paged_prefill(q, k_pages, v_pages, page_table, q_offset, kv_len,
         in_specs += [P(axis, None), P(axis, None)]
         args += [k_scales, v_scales]
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=tuple(in_specs),
-                       out_specs=P(), check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=P(), check_vma=False)
     def run(q_loc, kp, vp, table, q_off, klen, *scales):
         idx = jax.lax.axis_index(axis)
         perm = [(i, (i + 1) % n) for i in range(n)]

@@ -18,7 +18,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.ctx import pvary as _pvary
@@ -46,7 +45,7 @@ def pipelined_apply(params_stacked, x, body_fn, mesh: Mesh, *,
         return h
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
     )
@@ -78,7 +77,8 @@ def pipelined_apply(params_stacked, x, body_fn, mesh: Mesh, *,
         outs = outs * jnp.where(stage == s - 1, 1.0, 0.0).astype(outs.dtype)
         return jax.lax.psum(outs, axis)
 
-    ys = run(params_stacked, xs)
+    with jax.set_mesh(mesh):
+        ys = run(params_stacked, xs)
     return ys.reshape(b, *x.shape[1:])
 
 

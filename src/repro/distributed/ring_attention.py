@@ -26,7 +26,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.ctx import pvary as _pvary
@@ -52,7 +51,7 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis: str = "model",
     kv_lim = s if kv_len is None else kv_len
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )
     def run(q_loc, k_loc, v_loc):
         idx = jax.lax.axis_index(axis)

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.policy import DEFAULT_VMEM_BUDGET
 from repro.kernels.common import (
     NEG_INF,
     causal_tile_bounds as _causal_tile_bounds,
@@ -200,6 +201,7 @@ def mas_attention_flat(
     sm_scale: float | None = None,
     kv_resident: bool = True,
     kv_len: int | None = None,
+    vmem_limit_bytes: int = DEFAULT_VMEM_BUDGET,
     interpret: bool = False,
 ) -> jax.Array:
     bhq, nq, e = q.shape
@@ -269,8 +271,12 @@ def mas_attention_flat(
 
     kwargs = {}
     if not interpret:
+        # The compiler's scoped-VMEM limit is the budget the policy planned
+        # the working set against (core/policy.py), so a decision the
+        # policy accepts is one the compiler accepts.
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=dimension_semantics
+            dimension_semantics=dimension_semantics,
+            vmem_limit_bytes=vmem_limit_bytes,
         )
     if kv_resident:
         in_specs = [q_spec, kv_spec, kv_spec]

@@ -16,6 +16,7 @@ from repro.core.policy import (
     DEFAULT_VMEM_BUDGET,
     TilingConfig,
     choose_attention_method,
+    sublane_rows,
 )
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_flat
@@ -43,8 +44,7 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
 
 
 def _sublane_multiple(dtype) -> int:
-    # TPU minor-most-2 tiling: fp32 -> 8, bf16 -> 16, int8/fp8 -> 32.
-    return {4: 8, 2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
+    return sublane_rows(jnp.dtype(dtype).itemsize)
 
 
 @functools.partial(
@@ -119,7 +119,8 @@ def attention(
     )
     if method in ("mas_resident", "mas_streamed"):
         of = mas_attention_flat(
-            qf, kf, vf, kv_resident=(method == "mas_resident"), **common
+            qf, kf, vf, kv_resident=(method == "mas_resident"),
+            vmem_limit_bytes=vmem_budget, **common
         )
     elif method == "flash":
         of = flash_attention_flat(qf, kf, vf, window=window, **common)

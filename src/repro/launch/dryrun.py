@@ -25,6 +25,7 @@ import jax  # noqa: E402
 
 from repro.configs import SHAPES, all_cells, cell_is_runnable, get_arch  # noqa: E402
 from repro.distributed.ctx import sharding_policy  # noqa: E402
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_production_mesh, make_test_mesh  # noqa: E402
 from repro.launch.steps import cell_lowering_inputs  # noqa: E402
 from repro.analysis.hlo import collective_bytes_from_hlo  # noqa: E402
@@ -34,7 +35,7 @@ def run_cell(arch_id: str, shape_id: str, mesh, mesh_name: str) -> dict:
     cell = SHAPES[shape_id]
     t0 = time.time()
     step, args, donate, policy = cell_lowering_inputs(arch_id, cell, mesh)
-    with mesh, sharding_policy(mesh, policy):
+    with jax.set_mesh(mesh), sharding_policy(mesh, policy):
         lowered = jax.jit(step, donate_argnums=donate).lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -83,6 +84,7 @@ def main():
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--fail-fast", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     meshes = []
     if args.mesh in ("single", "both"):

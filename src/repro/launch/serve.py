@@ -13,6 +13,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch, get_smoke
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import Request, ServingEngine
 
@@ -27,6 +28,7 @@ def main(argv=None):
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
     model = build_model(cfg)

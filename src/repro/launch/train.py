@@ -29,6 +29,7 @@ from repro.data import SyntheticLMData
 from repro.distributed import sharding as shd
 from repro.distributed.compression import init_error_feedback
 from repro.distributed.elastic import StepTimer, Watchdog
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import (
     make_production_mesh,
     make_single_device_mesh,
@@ -75,6 +76,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-file", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
     overrides = {}
@@ -103,7 +105,7 @@ def main(argv=None):
         d_model=cfg.d_model,
     )
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params = jax.jit(model.init)(jax.random.PRNGKey(0))
         p_specs = shd.param_specs(params, mesh)
         params = jax.device_put(params, shd.named(mesh, p_specs))
