@@ -155,6 +155,7 @@ def decode_attention_flat(
         )
     return pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, g, e), q.dtype),
         interpret=interpret,

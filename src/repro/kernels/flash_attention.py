@@ -176,6 +176,7 @@ def flash_attention_flat(
         )
     return pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=in_specs,
         out_specs=o_spec,

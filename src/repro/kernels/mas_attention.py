@@ -284,6 +284,7 @@ def mas_attention_flat(
         in_specs = [q_spec, kv_k_spec, kv_v_spec]
     return pl.pallas_call(
         kernel,
+        name="mas_attention",
         grid=grid,
         in_specs=in_specs,
         out_specs=o_spec,

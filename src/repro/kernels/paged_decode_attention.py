@@ -155,6 +155,7 @@ def paged_decode_attention_flat(
         )
     return pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, e), q.dtype),
         interpret=interpret,

@@ -176,6 +176,7 @@ def paged_prefill_attention_flat(
         )
     return pl.pallas_call(
         kernel,
+        name="paged_prefill_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hq, chunk, e), q.dtype),
         interpret=interpret,

@@ -183,6 +183,7 @@ def paged_verify_attention_flat(
         )
     return pl.pallas_call(
         kernel,
+        name="paged_verify_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, e), q.dtype),
         interpret=interpret,

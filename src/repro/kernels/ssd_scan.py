@@ -71,6 +71,7 @@ def ssd_intra_chunk(x, a, b, c, *, interpret: bool = False):
         )
     return pl.pallas_call(
         kernel,
+        name="ssd_scan",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, q, p), lambda i, j: (i, j, 0, 0)),

@@ -35,11 +35,13 @@ benchmarks read — ``token_walltimes`` / ``occupancy_log`` /
 ``preemption_count`` / ``recompute_tokens`` remain as thin read-only
 views onto it — and an optional ``Tracer`` (DESIGN.md §8) that, when
 enabled, records per-request lifecycle spans driven by the
-``lifecycle.py`` state machine, per-step spans annotated with batch
-composition (compile-shape kind, chunk tokens, live decode slots) and
-the dispatch vs host-sync split, pool-occupancy counter tracks, and
-preemption/NaN instants. The default ``NULL_TRACER`` costs one
-truthiness check per step.
+``lifecycle.py`` state machine, per-step phase spans (``admit``, the
+speculative ``draft``, then ``step``, annotated with batch composition —
+compile-shape kind, chunk tokens, live decode slots — around ``pack``,
+``dispatch`` and ``host_sync``, then ``commit``), pool-occupancy counter
+tracks, and preemption/NaN instants; under a running ``jax.profiler``
+trace the phase spans land in the device trace as well. The default
+``NULL_TRACER`` costs one truthiness check per span.
 """
 
 from __future__ import annotations
@@ -588,11 +590,10 @@ class ContinuousBatchingEngine:
             page_size=self.page_size, num_pages=self.num_pages,
             kv_dtype=self.kv_dtype)
 
-    def _observe_step(self, kind: str, t0: float, t1: float,
-                      chunk_tokens: int, live: int) -> None:
+    def _observe_step(self, chunk_tokens: int, live: int) -> None:
         """Per-step observability hook, called once per engine step
-        after the host sync. No-op here; the sharded engine emits
-        per-shard span tracks and shard.* metrics from it."""
+        after the host sync. No-op here; the sharded engine records its
+        shard.* metrics from it."""
 
     def kv_bytes_per_page(self) -> int:
         cfg = self.cfg
@@ -968,64 +969,18 @@ class ContinuousBatchingEngine:
                 pending = [rec, slot, res.prefix_tokens, rprompt]
                 return
 
-        stalls = 0
-        while True:
-            self.injector.step_begin(self, step_idx)
-            sweep_kills(time.perf_counter())
-            if pending is None:
-                start_prefill()
-            if pending is None and not active:
-                if not queue:
-                    break
-                # nothing live but requests still queued: admission
-                # backpressure (injected rejection) with an idle engine.
-                # Spin the scheduler without dispatching a dead step —
-                # and refuse to spin forever if the injector never
-                # relents (a fault-script bug, not a serving condition).
-                stalls += 1
-                if stalls > 10_000:
-                    rec = queue.popleft()
-                    rec.fail("admission stalled (injected rejection)")
-                    stalls = 0
-                step_idx += 1
-                continue
-            stalls = 0
-            spec_plan = None
-            t_step0 = time.perf_counter()
-            t_draft1 = t_step0
-            if pending is None and self._verify is not None:
-                # speculative decode step: draft + reserve BEFORE the
-                # table snapshot, so reservation pages (and any
-                # reservation-driven preemption) are visible to it
-                spec_plan = plan_speculation()
-                t_draft1 = time.perf_counter()
-                if tracing:
-                    tr.complete("draft", tr.to_us(t_step0),
-                                (t_draft1 - t_step0) * 1e6, track="engine")
-                if not active:
-                    step_idx += 1
-                    continue  # reservation churn evicted every slot
-            m_occ.record(mgr.pages_used)
-            if self.prefix_cache:
-                m_px_resident.record(len(mgr.cached_pages()))
-                sync_prefix_metrics()
-            self.step_log.append({"prefill_in_flight": pending is not None,
-                                  "live_decode": len(active)})
-            kind = (("verify" if spec_plan is not None else "decode")
-                    if pending is None
-                    else ("chunk+decode" if active else "chunk"))
-            if tracing:
-                tr.counter("pool.pages_used", mgr.pages_used, track="pool")
+        def pack_step(spec_plan, clen: int):
+            """The step program and its packed host inputs: the page
+            table, and the decode batch, the prompt chunk or the verify
+            rows, each as one array."""
             dec_table = mgr.table()
             if pending is not None:
-                rec, slot, q0, rprompt = pending
+                _, slot, q0, rprompt = pending
                 # mid-admission the slot must not decode into (or read
                 # from) its half-written pages: point it at scratch
                 # (the prefill keeps the real row, captured first)
                 seq_table = dec_table[slot].copy()
                 dec_table[slot] = SCRATCH_PAGE
-                plen = len(rprompt)
-                clen = min(self.chunk_size, plen - q0)
                 ctokens = np.ones((1, self.chunk_size), np.int32)
                 ctokens[0, :clen] = rprompt[q0:q0 + clen]
                 # the chunk's page span; padded-tail pages past the
@@ -1042,54 +997,25 @@ class ContinuousBatchingEngine:
                 if active:
                     hs = np.concatenate([tokens[:, 0], positions,
                                          dec_table.ravel()])
-                    packed, cache = self._chunk_step(
-                        self.params, cache, jnp.asarray(hs), ch)
-                else:
-                    packed, cache = self._chunk_only(self.params, cache, ch)
-            elif spec_plan is not None:
+                    return self._chunk_step, (jnp.asarray(hs), ch)
+                return self._chunk_only, (ch,)
+            if spec_plan is not None:
                 vs_tokens, n_rows, _ = spec_plan
                 vs = np.concatenate([vs_tokens.ravel(), positions,
                                      n_rows, dec_table.ravel()])
-                packed, cache = self._verify(self.params, cache,
-                                             jnp.asarray(vs))
-            else:
-                hs = np.concatenate([tokens[:, 0], positions,
-                                     dec_table.ravel()])
-                packed, cache = self._decode(self.params, cache,
-                                             jnp.asarray(hs))
-            t_disp = time.perf_counter()
-            # the step's single device->host transfer carries decode
-            # tokens, (on the final chunk) the admitted request's first
-            # token, AND the finite-guard flags — no per-admit argmax
-            # sync, no second sync for the NaN guard
-            raw = np.asarray(packed)
-            now = time.perf_counter()
-            m_sync.observe(now - t_disp)
-            m_step_kind[kind].observe(now - t_step0)
-            self._observe_step(kind, t_step0, now,
-                               clen if pending is not None else 0,
-                               len(active))
-            if tracing:
-                # step span split: host-side pack + async dispatch vs
-                # the device->host sync that rides the step's transfer
-                tr.complete("step", tr.to_us(t_step0),
-                            (now - t_step0) * 1e6, track="engine", args={
-                                "kind": kind, "step": step_idx,
-                                "live_decode": len(active),
-                                "chunk_tokens": (clen if pending is not None
-                                                 else 0),
-                                "pages_used": mgr.pages_used,
-                            })
-                tr.complete("dispatch", tr.to_us(t_step0),
-                            (t_disp - t_step0) * 1e6, track="engine")
-                tr.complete("host_sync", tr.to_us(t_disp),
-                            (now - t_disp) * 1e6, track="engine")
-                if spec_plan is not None:
-                    # draft/verify split inside the step span: drafting
-                    # ended at t_draft1, the verify kernel's dispatch +
-                    # sync fills the rest
-                    tr.complete("verify", tr.to_us(t_draft1),
-                                (now - t_draft1) * 1e6, track="engine")
+                return self._verify, (jnp.asarray(vs),)
+            hs = np.concatenate([tokens[:, 0], positions,
+                                 dec_table.ravel()])
+            return self._decode, (jnp.asarray(hs),)
+
+        def commit_step(raw, now: float, spec_plan, clen: int) -> None:
+            """Commit one step's transfer on the host: per-slot tokens,
+            page appends, retirement and lifecycle, then the tail of the
+            prompt chunk (the admitted request's first token)."""
+            nonlocal pending, n_append
+            if pending is not None:
+                rec, slot, q0, rprompt = pending
+                plen = len(rprompt)
             half = raw.shape[0] // 2
             token_host = raw[:half]
             ok_host = np.asarray(
@@ -1245,11 +1171,85 @@ class ContinuousBatchingEngine:
                     pending = None
                 else:
                     pending[2] = q0
-            if self.auditor is not None:
-                expected = {s: int(positions[s]) for s in active}
-                if pending is not None:
-                    expected[pending[1]] = len(pending[3])
-                self.auditor.check(mgr, expected_lens=expected)
+
+        stalls = 0
+        while True:
+            self.injector.step_begin(self, step_idx)
+            with tr.span("admit", track="engine"):
+                sweep_kills(time.perf_counter())
+                if pending is None:
+                    start_prefill()
+            if pending is None and not active:
+                if not queue:
+                    break
+                # nothing live but requests still queued: admission
+                # backpressure (injected rejection) with an idle engine.
+                # Spin the scheduler without dispatching a dead step —
+                # and refuse to spin forever if the injector never
+                # relents (a fault-script bug, not a serving condition).
+                stalls += 1
+                if stalls > 10_000:
+                    rec = queue.popleft()
+                    rec.fail("admission stalled (injected rejection)")
+                    stalls = 0
+                step_idx += 1
+                continue
+            stalls = 0
+            spec_plan = None
+            t_step0 = time.perf_counter()
+            if pending is None and self._verify is not None:
+                # speculative decode step: draft + reserve BEFORE the
+                # table snapshot, so reservation pages (and any
+                # reservation-driven preemption) are visible to it
+                with tr.span("draft", track="engine"):
+                    spec_plan = plan_speculation()
+                if not active:
+                    step_idx += 1
+                    continue  # reservation churn evicted every slot
+            # read when the span closes: filled in once the batch is known
+            step_args = {"step": step_idx}
+            with tr.span("step", track="engine", args=step_args):
+                m_occ.record(mgr.pages_used)
+                if self.prefix_cache:
+                    m_px_resident.record(len(mgr.cached_pages()))
+                    sync_prefix_metrics()
+                # live prompt rows of this step's chunk (the rest is pad)
+                clen = (min(self.chunk_size, len(pending[3]) - pending[2])
+                        if pending is not None else 0)
+                self.step_log.append({"prefill_in_flight": pending is not None,
+                                      "live_decode": len(active),
+                                      "chunk_tokens": clen})
+                kind = (("verify" if spec_plan is not None else "decode")
+                        if pending is None
+                        else ("chunk+decode" if active else "chunk"))
+                step_args.update(kind=kind, live_decode=len(active),
+                                 chunk_tokens=clen,
+                                 pages_used=mgr.pages_used)
+                if tracing:
+                    tr.counter("pool.pages_used", mgr.pages_used,
+                               track="pool")
+                with tr.span("pack", track="engine"):
+                    step_fn, step_in = pack_step(spec_plan, clen)
+                with tr.span("dispatch", track="engine"):
+                    packed, cache = step_fn(self.params, cache, *step_in)
+                t_disp = time.perf_counter()
+                # the step's single device->host transfer carries decode
+                # tokens, (on the final chunk) the admitted request's first
+                # token, AND the finite-guard flags — no per-admit argmax
+                # sync, no second sync for the NaN guard
+                with tr.span("host_sync", track="engine"):
+                    raw = np.asarray(packed)
+                now = time.perf_counter()
+                m_sync.observe(now - t_disp)
+                m_step_kind[kind].observe(now - t_step0)
+                self._observe_step(clen, len(active))
+            with tr.span("commit", track="engine"):
+                commit_step(raw, now, spec_plan, clen)
+                if self.auditor is not None:
+                    expected = {s: int(positions[s]) for s in active}
+                    if pending is not None:
+                        expected[pending[1]] = len(pending[3])
+                    self.auditor.check(mgr, expected_lens=expected)
             step_idx += 1
         self.peak_pages_used = max(self.peak_pages_used,
                                    mgr.peak_pages_used)

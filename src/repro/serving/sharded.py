@@ -98,7 +98,7 @@ class ShardedContinuousBatchingEngine(ContinuousBatchingEngine):
         with ctx.kv_shard(self.mesh, self.mesh_axis):
             return super().serve(requests)
 
-    def _observe_step(self, kind, t0, t1, chunk_tokens, live):
+    def _observe_step(self, chunk_tokens, live):
         m = self.metrics
         m.gauge("shard.degree", "active mesh shard degree").record(
             self.shard)
@@ -115,13 +115,6 @@ class ShardedContinuousBatchingEngine(ContinuousBatchingEngine):
                 m.counter("shard.ring_hops",
                           "head-block ring ppermute hops (prefill)").inc(
                     (self.shard - 1) * self._num_units)
-        tr = self.tracer
-        if tr.enabled:
-            dur = (t1 - t0) * 1e6
-            for i in range(self.shard):
-                tr.complete(kind, tr.to_us(t0), dur, track=f"shard{i}",
-                            args={"shard": i, "live_decode": live,
-                                  "chunk_tokens": chunk_tokens})
 
     @property
     def shard_stats(self) -> dict:

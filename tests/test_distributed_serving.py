@@ -10,8 +10,8 @@ Four layers are pinned here:
   partial-hop causal masking matches the dense oracle;
 * the engine: the sharded continuous-batching engine is token-for-token
   the single-chip engine on GQA configs (fp32 + int8, through a §7
-  injected preemption burst, with the pool auditor attached), emits
-  per-shard span tracks + shard.* metrics, resolves ``shard="auto"``,
+  injected preemption burst, with the pool auditor attached), traces
+  the engine's step spans + shard.* metrics, resolves ``shard="auto"``,
   and the least-loaded router balances replicas;
 * the search: ``Tiling.shard`` is the eighth factor of grid/MCTS/GA and
   its optimum moves with the interconnect bandwidth (interior at the
@@ -379,14 +379,16 @@ def test_sharded_engine_spans_and_metrics():
     trace = tr.export()
     tracks = {ev["args"]["name"] for ev in trace["traceEvents"]
               if ev.get("ph") == "M" and ev.get("name") == "thread_name"}
-    assert {"shard0", "shard1"} <= tracks
-    tids = {ev["tid"] for ev in trace["traceEvents"]
-            if ev.get("ph") == "M" and ev["args"].get("name") == "shard0"}
-    spans = [ev for ev in trace["traceEvents"]
-             if ev.get("ph") == "X" and ev["tid"] in tids]
-    assert spans, "no per-shard step spans"
+    # one engine track: the host step is not repeated per shard
+    assert not any(t.startswith("shard") for t in tracks)
+    steps = [ev for ev in trace["traceEvents"]
+             if ev.get("ph") == "X" and ev["name"] == "step"]
+    assert [ev["args"]["kind"] for ev in steps] == [
+        ("decode" if not e["prefill_in_flight"]
+         else "chunk+decode" if e["live_decode"] else "chunk")
+        for e in eng.step_log]
     g = eng.metrics.gauge("shard.degree")
-    assert g.series and g.series[-1] == 2
+    assert len(g.series) == len(steps) and g.series[-1] == 2
 
 
 def test_shard_auto_and_validation():

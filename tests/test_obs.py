@@ -107,6 +107,68 @@ def test_span_nesting_and_ordering():
     assert validate_chrome_trace(tr.export()) == []
 
 
+def test_disabled_tracer_opens_no_annotation(monkeypatch):
+    from repro.obs import trace as trace_mod
+
+    def refuse():
+        raise AssertionError("a disabled tracer reached the profiler")
+
+    monkeypatch.setattr(trace_mod, "_annotation", refuse)
+    tr = Tracer(enabled=False)
+    span = tr.span("step", track="engine", args={"k": 1})
+    assert span is trace_mod._NULL_SPAN
+    with span:
+        pass
+    assert tr.export()["traceEvents"] == []
+
+
+def test_profiler_sink_annotates_device_trace(tmp_path):
+    """Under a running profiler trace, an enabled tracer's spans land in
+    it as ``<track>.<name>`` host events, nested as they ran, with the
+    args the span held when it closed as event stats; the in-memory trace
+    is unchanged by the sink."""
+    import glob
+
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        args = {"kind": "decode"}
+        with tr.span("step", track="engine", args=args):
+            with tr.span("pack", track="engine"):
+                jnp.ones(8).block_until_ready()
+            with tr.span("commit", track="engine"):
+                pass
+            args["step"] = 3  # filled in before the span closes
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    evs = {e.name: e for plane in ProfileData.from_file(path).planes
+           if plane.name.startswith("/host:")
+           for line in plane.lines for e in line.events
+           if e.name.startswith("engine.")}
+    assert set(evs) == {"engine.step", "engine.pack", "engine.commit"}
+    step, pack, commit = (evs[f"engine.{n}"] for n in
+                          ("step", "pack", "commit"))
+    assert dict(step.stats) == {"kind": "decode", "step": 3}
+    assert dict(pack.stats) == {}
+
+    def end(e):
+        return e.start_ns + e.duration_ns
+
+    assert step.start_ns <= pack.start_ns < end(pack) <= commit.start_ns
+    assert end(commit) <= end(step)
+    out = tr.export()
+    assert validate_chrome_trace(out) == []
+    assert [(e["name"], e.get("args")) for e in out["traceEvents"]
+            if e["ph"] == "X"] == [("step", {"kind": "decode", "step": 3}),
+                                   ("pack", None), ("commit", None)]
+
+
 def test_ring_buffer_truncation_is_flagged():
     tr = Tracer(max_events=4)
     for k in range(10):
@@ -442,6 +504,68 @@ def test_cont_engine_trace_lifecycle_and_steps(smoke, cont_engine):
     assert cont_engine.occupancy_log
     assert set(cont_engine.token_walltimes) == {0, 1, 2}
     assert cont_engine.preemption_count == 0
+
+
+def _kind(entry) -> str:
+    if not entry["prefill_in_flight"]:
+        return "decode"
+    return "chunk+decode" if entry["live_decode"] else "chunk"
+
+
+def test_cont_engine_step_phase_spans(smoke, cont_engine):
+    """Each engine step is an ``admit`` span, a ``step`` span holding
+    pack, dispatch and host_sync in that order, then a ``commit`` span;
+    the step's args are its ``step_log`` entry; the chunk rows add up to
+    the prompts; and each step-time histogram value still runs from the
+    step's start to the end of its host sync."""
+    cfg, _, _ = smoke
+    spec = [(5, 4), (19, 3), (13, 2)]  # one prompt takes three chunks
+    out, trace = _traced_serve(cont_engine, cfg, spec)
+    phases = ("admit", "step", "draft", "pack", "dispatch", "host_sync",
+              "commit")
+    evs = sorted((e for e in trace["traceEvents"]
+                  if e["ph"] == "X" and e["name"] in phases),
+                 key=lambda e: (e["ts"], -e["dur"]))
+    assert len({e["tid"] for e in evs}) == 1  # all on the engine track
+    steps = [e for e in evs if e["name"] == "step"]
+    log = cont_engine.step_log
+    assert len(steps) == len(log)
+    hist = {k: list(cont_engine.metrics.histogram(
+        f"engine.step_s.{k}").values) for k in ("decode", "chunk",
+                                                 "chunk+decode")}
+    assert sum(map(len, hist.values())) == len(steps)
+    used = dict.fromkeys(hist, 0)
+    for i, (st, entry) in enumerate(zip(steps, log)):
+        end = st["ts"] + st["dur"]
+        inside = [e for e in evs if st["ts"] <= e["ts"] and
+                  e["ts"] + e["dur"] <= end and e is not st]
+        assert [e["name"] for e in inside] == [
+            "pack", "dispatch", "host_sync"]
+        # the step's neighbours on the track: its admit, then its commit
+        j = evs.index(st)
+        admit, commit = evs[j - 1], evs[j + len(inside) + 1]
+        assert (admit["name"], commit["name"]) == ("admit", "commit")
+        assert admit["ts"] + admit["dur"] <= st["ts"]
+        assert end <= commit["ts"]
+        assert st["args"] == {"step": st["args"]["step"],
+                              "kind": _kind(entry),
+                              "live_decode": entry["live_decode"],
+                              "chunk_tokens": entry["chunk_tokens"],
+                              "pages_used": cont_engine.occupancy_log[i]}
+        # the histogram's interval opens between admit and step and
+        # closes between host_sync and commit (the clocks are one)
+        kind = st["args"]["kind"]
+        value = hist[kind][used[kind]]
+        used[kind] += 1
+        sync = inside[2]
+        eps = 1e-9
+        assert (sync["ts"] + sync["dur"] - st["ts"]) * 1e-6 - eps <= value
+        assert value <= (commit["ts"] - admit["ts"] - admit["dur"]) * 1e-6 \
+            + eps
+    rows = sum(n for n, _ in spec)
+    assert sum(e["chunk_tokens"] for e in log) == rows
+    assert max(e["chunk_tokens"] for e in log) == cont_engine.chunk_size
+    assert all(len(out[i]) == m for i, (_, m) in enumerate(spec))
 
 
 def test_cont_engine_trace_preemption_nesting(smoke, cont_engine):

@@ -372,7 +372,8 @@ def test_speculative_with_injected_preemption():
 
 def test_speculative_trace_carries_verify_steps():
     """Verify steps are traced with kind="verify" (mapped to the
-    compare phase), draft/verify sub-spans, and speculation instants."""
+    compare phase), a draft span before each verify step's
+    pack/dispatch/sync, then its commit, and speculation instants."""
     from repro.obs import DEFAULT_KIND_TO_PHASE, Tracer, validate_chrome_trace
     from repro.serving import ContinuousBatchingEngine
 
@@ -389,8 +390,13 @@ def test_speculative_trace_carries_verify_steps():
              for e in evs if e.get("name") == "step" and e.get("ph") == "X"}
     assert "verify" in kinds
     assert DEFAULT_KIND_TO_PHASE["verify"] == "verify"
-    names = {e.get("name") for e in evs}
-    assert "draft" in names and "verify" in names
+    names = [e.get("name") for e in evs if e.get("ph") == "X"
+             and e.get("name") in ("draft", "pack", "dispatch",
+                                   "host_sync", "commit")]
+    # spans close in order: draft first in every verify step
+    i = names.index("draft")
+    assert names[i:i + 5] == ["draft", "pack", "dispatch", "host_sync",
+                              "commit"]
     inst = [e for e in evs if e.get("ph") == "i"
             and e.get("name") == "speculation"]
     assert inst and all("accepted" in (e.get("args") or {}) for e in inst)

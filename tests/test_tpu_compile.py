@@ -8,6 +8,7 @@ policy's own decisions and qwen3-1.7b's published widths.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +68,14 @@ def _sds(shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _custom_calls(compiled) -> set:
+    """The custom calls of a compiled program by name, less the
+    instruction's numeric suffix: the names a device trace's op events
+    carry, which the benchmark's kernel readers look for."""
+    return {re.sub(r"\.\d+$", "", c) for c in re.findall(
+        r"%([\w\-.]+) = [^\n]*custom-call", compiled.as_text())}
+
+
 @pytest.mark.parametrize("n,causal", [
     (2048, True), (8192, True), (32768, True),
     # the policy's boundaries: the largest resident N, the largest N
@@ -94,27 +103,30 @@ BATCH, MAX_PAGES, POOL = 8, 128, 1025
 @pytest.mark.parametrize("page", [16, 64])
 def test_paged_decode_kernel_compiles(compile_for_chip, page):
     pool = _sds((HKV, POOL, page, E))
-    compile_for_chip(
+    compiled = compile_for_chip(
         kops.paged_decode_attention, _sds((BATCH, HQ, E)), pool, pool,
         _sds((BATCH, MAX_PAGES), jnp.int32), _sds((BATCH,), jnp.int32))
+    assert "paged_decode_attention" in _custom_calls(compiled)
 
 
 @pytest.mark.parametrize("page", [16, 64])
 def test_paged_verify_kernel_compiles(compile_for_chip, page):
     pool = _sds((HKV, POOL, page, E))
-    compile_for_chip(
+    compiled = compile_for_chip(
         kops.paged_verify_attention, _sds((BATCH, 4, HQ, E)), pool, pool,
         _sds((BATCH, MAX_PAGES), jnp.int32), _sds((BATCH,), jnp.int32),
         _sds((BATCH,), jnp.int32))
+    assert "paged_verify_attention" in _custom_calls(compiled)
 
 
 @pytest.mark.parametrize("page", [16, 64])
 def test_paged_prefill_kernel_compiles(compile_for_chip, page):
     pool = _sds((HKV, POOL, page, E))
-    compile_for_chip(
+    compiled = compile_for_chip(
         kops.paged_prefill_attention, _sds((HQ, 512, E)), pool, pool,
         _sds((MAX_PAGES,), jnp.int32), _sds((), jnp.int32),
         _sds((), jnp.int32))
+    assert "paged_prefill_attention" in _custom_calls(compiled)
 
 
 def test_qwen3_paged_decode_step_compiles(compile_for_chip):
