@@ -12,7 +12,11 @@ runs three phases, and any failure exits non-zero:
    ``flash``, and each MAS variant the policy accepts. Each output is
    checked against the fp32 ``repro.kernels.ref.attention`` on a few
    256-row query blocks (a dense fp32 reference at N=32768 would need
-   ~68 GB of scores).
+   ~68 GB of scores). Then ``repro.kernels.ops.paged_decode_attention``
+   at the benchmark's decode shapes (16 slots of 80 pages of 64 rows
+   over a pool of 577), with bf16 pools and with int8 pools and their
+   per-page scales, against the fp32 oracle over the same (dequantized)
+   pages.
 2. serving: qwen3-1.7b at published widths, random weights from a seed,
    ``attn_impl="pallas"``, through ``ContinuousBatchingEngine``: 16
    requests (prompts of 128-1536 tokens, 32 new tokens each) at batch 8.
@@ -55,6 +59,9 @@ KERNEL_NS = (2048, 8192, 32768)
 KERNEL_HEADS = (16, 8)  # (Hq, Hkv)
 HEAD_DIM = 128
 REF_ROWS = 256
+# the paged decode kernel's check: internlm2-1.8b.reasoning's slots,
+# pages a slot, page rows and pool pages
+PAGED_SLOTS, PAGED_MAX_PAGES, PAGED_PAGE, PAGED_POOL = 16, 80, 64, 577
 # bf16 tolerance of the kernel tests (atol = rtol)
 KERNEL_TOL = 3e-2
 # Pallas against XLA-twin logits: max |difference| over max |logit|. Both
@@ -173,6 +180,77 @@ def kernel_phase(ns=KERNEL_NS, heads=KERNEL_HEADS, e=HEAD_DIM,
                 f"rows {starts} (+{ref_rows}), tol {KERNEL_TOL}; "
                 f"compile {compile_s:.2f}s, first call {run_s:.3f}s "
                 f"(host clock)")
+
+
+def paged_kernel_phase(slots=PAGED_SLOTS, max_pages=PAGED_MAX_PAGES,
+                       page=PAGED_PAGE, pool_pages=PAGED_POOL,
+                       heads=KERNEL_HEADS, e=HEAD_DIM,
+                       interpret=False) -> None:
+    """The paged decode kernel with bf16 and with int8 pools against the
+    fp32 oracle over the pages it reads, at kv_len 0, 1, one page, a
+    whole number of the kernel's blocks and one past, the full table,
+    and random lengths."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import ops as kops
+    from repro.kernels import ref
+    from repro.kernels.common import dequantize_q8, quantize_q8
+
+    hq, hkv = heads
+    s_max = max_pages * page
+    rng = np.random.default_rng(SEED)
+    lens = rng.integers(1, s_max + 1, size=slots)
+    lens[:6] = (0, 1, page, 16 * page, 16 * page + 1, s_max)
+    lens = jnp.asarray(lens, jnp.int32)
+    table = jnp.asarray(rng.integers(1, pool_pages, size=(slots, max_pages)),
+                        jnp.int32)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(SEED), 3)
+    q = jax.random.normal(kq, (slots, hq, e), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (hkv, pool_pages, page, e), jnp.bfloat16)
+            for key in (kk, kv))
+    axes = (-2, -1)  # one scale per (kv head, page)
+    (k8, ks), (v8, vs) = quantize_q8(k, axes), quantize_q8(v, axes)
+
+    def dense(pool):
+        """(Hkv, P, page, E) -> each slot's (Hkv, S, E) cache, fp32."""
+        return jnp.moveaxis(pool[:, table], 0, 1).reshape(
+            slots, hkv, s_max, e).astype(jnp.float32)
+
+    @jax.jit
+    def oracle(kp, vp):
+        with jax.default_matmul_precision("highest"):
+            return jax.vmap(lambda q1, k1, v1, n: ref.decode_attention(
+                q1[None], k1[None], v1[None], n)[0])(
+                    q, dense(kp), dense(vp), lens)
+
+    def kernel(q_, kp, vp, tbl, kv_lens, *scales):
+        ksc, vsc = scales or (None, None)
+        return kops.paged_decode_attention(q_, kp, vp, tbl, kv_lens,
+                                           k_scales=ksc, v_scales=vsc,
+                                           interpret=interpret)
+
+    live = np.asarray(lens) > 0  # an empty slot's output is not attention
+    for name, pools, scales, exact in (
+            ("bf16", (k, v), (), (k, v)),
+            ("int8", (k8, v8), (ks, vs),
+             (dequantize_q8(k8, ks, axes), dequantize_q8(v8, vs, axes)))):
+        args = (q, *pools, table, lens, *scales)
+        t0 = time.perf_counter()
+        compiled = jax.jit(kernel).lower(*args).compile()
+        compile_s = time.perf_counter() - t0
+        _require_kernel(compiled, f"paged decode {name}")
+        out, run_s = _timed(compiled, *args)
+        got = np.asarray(out.astype(jnp.float32))[live]
+        want = np.asarray(oracle(*exact))[live]
+        np.testing.assert_allclose(got, want, atol=KERNEL_TOL,
+                                   rtol=KERNEL_TOL,
+                                   err_msg=f"paged decode {name}")
+        log(f"paged decode {name} pools: {slots} slots x {max_pages} pages "
+            f"of {page} rows, pool {pool_pages}: max|out-ref|="
+            f"{float(np.max(np.abs(got - want))):.3e}, tol {KERNEL_TOL}; "
+            f"compile {compile_s:.2f}s, first call {run_s:.3f}s "
+            f"(host clock)")
 
 
 # ---------------------------------------------------------------------------
@@ -417,6 +495,7 @@ def main(argv=None) -> int:
         sharded_phase(dataclasses.replace(arch, attn_impl="xla"), requests)
     else:
         kernel_phase()
+        paged_kernel_phase()
         log(f"phase kernels done at {time.perf_counter() - t0:.1f}s")
         cfg = dataclasses.replace(arch, attn_impl="pallas")
         params = serving_phase(cfg, requests)

@@ -29,6 +29,10 @@ prints one JSON line:
 - ``chunk_fill_pct``: live prompt rows (the ``chunk_tokens`` stat) of
   the window's steps that carry a chunk, over their count times the chunk
   size;
+- ``decode_live_page_pct``: the key pages the decode attention reads
+  (the ``kv_pages_live`` stat) over the page table's entries (slots times
+  pages a slot), mean over the window's decode steps; null when reducing
+  a kept trace, which does not hold the table's shape;
 - ``device_offset_ms``: the bounds on the device's clock minus the
   host's that causality gives (each program run starts after its
   ``dispatch`` span starts and ends before its ``host_sync`` span ends).
@@ -223,9 +227,11 @@ def host_idle_s(by_label: dict) -> float:
 
 
 def reduce(rec: Recording, *, chunk_size: int,
-           window_ns: tuple[int, int] | None = None) -> dict:
+           window_ns: tuple[int, int] | None = None,
+           table_pages: int | None = None) -> dict:
     """The numbers of the module doc over ``window_ns`` (host clock of the
-    trace; the span of the device's ops when None)."""
+    trace; the span of the device's ops when None). ``table_pages`` is
+    the page table's entries, slots times pages a slot."""
     from bench import trace_reduce
 
     if not rec.ops:
@@ -257,6 +263,8 @@ def reduce(rec: Recording, *, chunk_size: int,
             programs.setdefault(name, []).append((b - a) * 1e-6)
     rows = [sp[3]["chunk_tokens"] for sp in steps
             if sp[3]["kind"] in CHUNK_KINDS]
+    live = [sp[3]["kv_pages_live"] for sp in steps
+            if sp[3]["kind"] == "decode" and "kv_pages_live" in sp[3]]
     return {
         "window_s": window_s,
         "busy_s": busy_s,
@@ -272,6 +280,9 @@ def reduce(rec: Recording, *, chunk_size: int,
                      for n, d in sorted(programs.items())},
         "chunk_fill_pct": (100.0 * sum(rows) / (len(rows) * chunk_size)
                            if rows else None),
+        "decode_live_page_pct": (
+            100.0 * sum(live) / (len(live) * table_pages)
+            if live and table_pages else None),
         "device_offset_ms": [b * 1e-6 for b in bounds] if bounds else None,
     }
 
@@ -317,9 +328,11 @@ def run_window(args) -> dict:
                                cs.bench_dir)
     # the window on the trace's host clock, as the harness read it
     window = seen["window_ns"]
+    eng = system.engine
     out = {"workload": args.workload, "seed": args.seed,
            **reduce(load(kept), chunk_size=ctx.chunk_size,
-                    window_ns=window)}
+                    window_ns=window,
+                    table_pages=eng.batch_size * eng.max_pages)}
     for kind, entries in (("end_to_end", cs.end_to_end),
                           ("layer_metrics", cs.per_layer)):
         out[kind] = {m["name"]: spec.load_reader(cs.bench_dir, kind,

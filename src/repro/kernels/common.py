@@ -106,10 +106,9 @@ def three_band_select(s, q0, col0, kv_len, *, rows_per_pos: int = 1):
 def mask_kv_tail(s, col0, kv_len):
     """Mask score columns whose absolute kv position is >= ``kv_len``.
 
-    ``s`` is a (rows, blk_kv) score tile whose first column sits at
+    ``s`` is a (..., rows, blk_kv) score tile whose first column sits at
     absolute kv position ``col0``; positions past the live cache length
     are forced to NEG_INF so they contribute exp(.) == 0 downstream.
     """
-    rows, blk_kv = s.shape
-    cols = jax.lax.broadcasted_iota(jnp.int32, (rows, blk_kv), 1) + col0
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1) + col0
     return jnp.where(cols < kv_len, s, NEG_INF)

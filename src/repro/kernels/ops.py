@@ -22,7 +22,10 @@ from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_flat
 from repro.kernels.flash_attention import flash_attention_flat
 from repro.kernels.mas_attention import mas_attention_flat
-from repro.kernels.paged_decode_attention import paged_decode_attention_flat
+from repro.kernels.paged_decode_attention import (
+    decode_pages_per_block,
+    paged_decode_attention_flat,
+)
 from repro.kernels.paged_prefill_attention import paged_prefill_attention_flat
 from repro.kernels.paged_verify_attention import paged_verify_attention_flat
 
@@ -209,6 +212,8 @@ def paged_decode_attention(
 
     of = paged_decode_attention_flat(
         qg, k_pages, v_pages, page_table, kv_lens,
+        pages_per_block=decode_pages_per_block(
+            hkv, page_size, e, k_pages.dtype.itemsize, page_table.shape[1]),
         sm_scale=sm_scale, k_scales=k_scales, v_scales=v_scales,
         interpret=interp,
     )

@@ -9,11 +9,11 @@ instead of once per position as k serial decode steps would — amortizes
 the dominant cost k-fold while the argmax over each position's logits
 lets the host accept exactly the greedy-matching draft prefix.
 
-Structurally this kernel is the batched paged decode kernel
-(``paged_decode_attention.py``: grid (B, Hkv, max_pages), scalar-prefetch
-page-table gather, clamped dead pages, online softmax in scratch) with
-the prefill kernel's §3 three-band causal banding folded in, the k-block
-playing the diagonal tile:
+Structurally this kernel is batched over slots like the paged decode
+kernel, on a grid (B, Hkv, max_pages) whose index maps gather pages
+through the scalar-prefetched page table (clamped dead pages, online
+softmax in scratch), with the prefill kernel's §3 three-band causal
+banding folded in, the k-block playing the diagonal tile:
 
 * the Q block row ``i`` holds query-head ``i % G`` of speculative
   position ``i // G`` (position-major (k·G, E) layout, G = padded GQA

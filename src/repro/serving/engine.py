@@ -1228,6 +1228,10 @@ class ContinuousBatchingEngine:
                 if tracing:
                     tr.counter("pool.pages_used", mgr.pages_used,
                                track="pool")
+                    # pages the decode attention reads: each live slot's
+                    # keys through the token this step writes
+                    step_args["kv_pages_live"] = sum(
+                        int(positions[s]) // ps + 1 for s in active)
                 with tr.span("pack", track="engine"):
                     step_fn, step_in = pack_step(spec_plan, clen)
                 with tr.span("dispatch", track="engine"):

@@ -551,7 +551,11 @@ def test_cont_engine_step_phase_spans(smoke, cont_engine):
                               "kind": _kind(entry),
                               "live_decode": entry["live_decode"],
                               "chunk_tokens": entry["chunk_tokens"],
-                              "pages_used": cont_engine.occupancy_log[i]}
+                              "pages_used": cont_engine.occupancy_log[i],
+                              "kv_pages_live": st["args"]["kv_pages_live"]}
+        # the decode slots' key pages: at least one a slot, all held
+        assert (entry["live_decode"] <= st["args"]["kv_pages_live"]
+                <= cont_engine.occupancy_log[i])
         # the histogram's interval opens between admit and step and
         # closes between host_sync and commit (the clocks are one)
         kind = st["args"]["kind"]

@@ -44,15 +44,18 @@ def _scatter_pool(kd, vd, page_size, rng):
     return k_pool, v_pool, table
 
 
-def _check_paged_parity(seed, b, group, hkv, page_size, mp, e, path):
+def _check_paged_parity(seed, b, group, hkv, page_size, mp, e, path,
+                        kv_lens=None):
     rng = np.random.default_rng(seed)
     s = page_size * mp
     hq = group * hkv
     q = jnp.asarray(rng.standard_normal((b, hq, e)), jnp.float32)
     kd = rng.standard_normal((b, hkv, s, e)).astype(np.float32)
     vd = rng.standard_normal((b, hkv, s, e)).astype(np.float32)
-    kv_lens = rng.integers(0, s + 1, size=b).astype(np.int32)
-    kv_lens[0] = s  # always cover the full-cache edge
+    if kv_lens is None:
+        kv_lens = rng.integers(0, s + 1, size=b).astype(np.int32)
+        kv_lens[0] = s  # always cover the full-cache edge
+    kv_lens = np.asarray(kv_lens, np.int32)
     k_pool, v_pool, table = _scatter_pool(kd, vd, page_size, rng)
 
     fn = paged_decode_attention if path == "pallas" else model_paged
@@ -70,12 +73,46 @@ def _check_paged_parity(seed, b, group, hkv, page_size, mp, e, path):
         )
 
 
+# Small shapes: every slot's pages in one kernel block. At the cells'
+# widths (8 KV heads of 128, fp32 pages of 128 rows) a block holds 4 of
+# the 6 or 5 pages, so slots run 1-2 blocks: kv_len on a block boundary
+# (512) and one past it, one live page, kv_len 0 first, last and next
+# to full slots, and a table that is not a whole number of blocks.
+PARITY_CASES = [
+    (group, hkv, page_size, mp, 16, None)
+    for group, hkv in [(1, 2), (2, 2), (4, 1), (8, 2)]
+    for page_size, mp in [(8, 4), (16, 2), (32, 3)]
+] + [
+    (2, 8, 128, 6, 128, (768, 0, 768, 512, 513, 37)),
+    (1, 8, 128, 5, 128, (0, 513, 1, 0)),
+]
+
+
 @pytest.mark.parametrize("path", ["pallas", "xla"])
-@pytest.mark.parametrize("group,hkv", [(1, 2), (2, 2), (4, 1), (8, 2)])
-@pytest.mark.parametrize("page_size,mp", [(8, 4), (16, 2), (32, 3)])
-def test_paged_decode_matches_dense(path, group, hkv, page_size, mp):
-    _check_paged_parity(seed=group * 100 + page_size + mp, b=3, group=group,
-                        hkv=hkv, page_size=page_size, mp=mp, e=16, path=path)
+@pytest.mark.parametrize("group,hkv,page_size,mp,e,kv_lens", PARITY_CASES)
+def test_paged_decode_matches_dense(path, group, hkv, page_size, mp, e,
+                                    kv_lens):
+    b = 3 if kv_lens is None else len(kv_lens)
+    _check_paged_parity(seed=group * 100 + page_size + mp, b=b, group=group,
+                        hkv=hkv, page_size=page_size, mp=mp, e=e, path=path,
+                        kv_lens=kv_lens)
+
+
+def test_decode_block_size_from_shapes():
+    from repro.kernels.paged_decode_attention import (
+        DECODE_VMEM_BUDGET,
+        decode_pages_per_block,
+    )
+
+    # the cells: 8 KV heads of 128, bf16 pages of 64 rows, 80 per slot
+    ppb = decode_pages_per_block(8, 64, 128, 2, 80)
+    assert ppb == 16
+    assert 4 * ppb * 8 * 64 * 128 * 2 <= DECODE_VMEM_BUDGET < 16 * 2**20
+    assert decode_pages_per_block(8, 64, 128, 1, 80) == 2 * ppb  # int8
+    # the parity shapes above
+    assert decode_pages_per_block(2, 32, 16, 4, 3) == 3
+    assert decode_pages_per_block(8, 128, 128, 4, 6) == 4
+    assert decode_pages_per_block(8, 2048, 128, 4, 6) == 1
 
 
 def test_paged_decode_hypothesis():

@@ -89,15 +89,18 @@ def _quant_pool(kd, vd, page_size, rng):
     return pools["k"], pools["v"], table
 
 
-def _check_int8_paged_parity(seed, b, group, hkv, page_size, mp, e):
+def _check_int8_paged_parity(seed, b, group, hkv, page_size, mp, e,
+                             kv_lens=None):
     rng = np.random.default_rng(seed)
     s = page_size * mp
     hq = group * hkv
     q = jnp.asarray(rng.standard_normal((b, hq, e)), jnp.float32)
     kd = rng.standard_normal((b, hkv, s, e)).astype(np.float32)
     vd = rng.standard_normal((b, hkv, s, e)).astype(np.float32)
-    kv_lens = rng.integers(0, s + 1, size=b).astype(np.int32)
-    kv_lens[0] = s
+    if kv_lens is None:
+        kv_lens = rng.integers(0, s + 1, size=b).astype(np.int32)
+        kv_lens[0] = s
+    kv_lens = np.asarray(kv_lens, np.int32)
     (k_pool, k_sc), (v_pool, v_sc), table = _quant_pool(kd, vd, page_size,
                                                         rng)
     args = (q, jnp.asarray(k_pool), jnp.asarray(v_pool), jnp.asarray(table),
@@ -130,13 +133,26 @@ def _check_int8_paged_parity(seed, b, group, hkv, page_size, mp, e):
                                    atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("group,hkv", [(1, 2), (2, 2), (4, 1), (8, 2)])
-@pytest.mark.parametrize("page_size,mp", [(8, 4), (16, 2), (32, 3)])
+# As test_paged_cache's parity cases: int8 pages of the cells' widths
+# (8 KV heads of 128, 128 rows) make blocks of 16 pages, so a table of
+# 17 runs two blocks, with kv_len on the boundary (2048) and one past it.
+INT8_PARITY_CASES = [
+    (group, hkv, page_size, mp, 16, None)
+    for group, hkv in [(1, 2), (2, 2), (4, 1), (8, 2)]
+    for page_size, mp in [(8, 4), (16, 2), (32, 3)]
+] + [
+    (2, 8, 128, 17, 128, (2176, 0, 2048, 2049, 100)),
+]
+
+
+@pytest.mark.parametrize("group,hkv,page_size,mp,e,kv_lens",
+                         INT8_PARITY_CASES)
 def test_int8_paged_kernel_matches_twin_and_oracle(group, hkv, page_size,
-                                                   mp):
-    _check_int8_paged_parity(seed=group * 71 + page_size + mp, b=3,
+                                                   mp, e, kv_lens):
+    b = 3 if kv_lens is None else len(kv_lens)
+    _check_int8_paged_parity(seed=group * 71 + page_size + mp, b=b,
                              group=group, hkv=hkv, page_size=page_size,
-                             mp=mp, e=16)
+                             mp=mp, e=e, kv_lens=kv_lens)
 
 
 def test_int8_paged_hypothesis():

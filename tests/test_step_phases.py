@@ -126,6 +126,22 @@ def test_reduce_splits_all_idle_and_host_idle_is_part_of_it(sp):
     assert out["chunk_fill_pct"] == pytest.approx(100 * 32 / 64)
 
 
+def test_reduce_reads_decode_live_pages(sp):
+    rec = _recording(sp, STEPS)
+    steps = [e[3] for e in rec.spans if e[0] == "step"]
+    # hand-made kv_pages_live: both decode steps, and a chunk+decode step
+    # that the mean leaves out
+    for stats, pages in zip(steps[1:], (999, 30, 50)):
+        stats["kv_pages_live"] = pages
+    out = sp.reduce(rec, chunk_size=32, window_ns=(0, 36 * MS),
+                    table_pages=16 * 80)
+    assert out["decode_live_page_pct"] == pytest.approx(
+        100 * (30 + 50) / 2 / (16 * 80))
+    # without the table's shape there is no reading
+    assert sp.reduce(rec, chunk_size=32, window_ns=(0, 36 * MS))[
+        "decode_live_page_pct"] is None
+
+
 @pytest.fixture(scope="module")
 def recording(sp):
     return sp.load(TRACE)

@@ -18,6 +18,10 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_arch
 from repro.core.policy import choose_attention_method
 from repro.kernels import ops as kops
+from repro.kernels.paged_decode_attention import (
+    DECODE_VMEM_BUDGET,
+    decode_pages_per_block,
+)
 from repro.models import build_model
 
 HQ, HKV, E = 16, 8, 128  # qwen3-1.7b attention widths
@@ -100,12 +104,34 @@ def test_flash_compiles_at_32k(compile_for_chip):
 BATCH, MAX_PAGES, POOL = 8, 128, 1025
 
 
-@pytest.mark.parametrize("page", [16, 64])
-def test_paged_decode_kernel_compiles(compile_for_chip, page):
-    pool = _sds((HKV, POOL, page, E))
-    compiled = compile_for_chip(
-        kops.paged_decode_attention, _sds((BATCH, HQ, E)), pool, pool,
-        _sds((BATCH, MAX_PAGES), jnp.int32), _sds((BATCH,), jnp.int32))
+@pytest.mark.parametrize("batch,page,max_pages,pool_pages,dtype", [
+    (BATCH, 16, MAX_PAGES, POOL, jnp.bfloat16),
+    (BATCH, 64, MAX_PAGES, POOL, jnp.bfloat16),
+    # the benchmark cells: 16 (internlm2-1.8b) and 20 (qwen3-1.7b) slots
+    # of 80 pages of 64 rows over a pool of 577, and the same with int8
+    # pools (twice the pages a block, each dequantized to fp32)
+    (16, 64, 80, 577, jnp.bfloat16), (20, 64, 80, 577, jnp.bfloat16),
+    (16, 64, 80, 577, jnp.int8), (20, 64, 80, 577, jnp.int8),
+])
+def test_paged_decode_kernel_compiles(compile_for_chip, batch, page,
+                                      max_pages, pool_pages, dtype):
+    pool = _sds((HKV, pool_pages, page, E), dtype)
+    shapes = [_sds((batch, HQ, E)), pool, pool,
+              _sds((batch, max_pages), jnp.int32), _sds((batch,), jnp.int32)]
+    fn = kops.paged_decode_attention
+    if dtype == jnp.int8:
+        scales = _sds((HKV, pool_pages), jnp.float32)
+        shapes += [scales, scales]
+
+        def fn(q, k, v, table, lens, ks, vs):
+            return kops.paged_decode_attention(q, k, v, table, lens,
+                                               k_scales=ks, v_scales=vs)
+    compiled = compile_for_chip(fn, *shapes)
+    # the compile holds the kernel to v5e's scoped VMEM; its K and V
+    # double buffers take the fixed budget's share of it
+    itemsize = jnp.dtype(dtype).itemsize
+    ppb = decode_pages_per_block(HKV, page, E, itemsize, max_pages)
+    assert 4 * ppb * HKV * page * E * itemsize <= DECODE_VMEM_BUDGET
     assert "paged_decode_attention" in _custom_calls(compiled)
 
 
