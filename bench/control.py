@@ -8,7 +8,8 @@ cell's window at the cell's load (as ``bench/run.py`` serves it), then
 on the requests that a run checks, the gaps between the float32
 reference's best logit and its logit of (a) the token the program
 served and (b) the token that the reference computed in int8 (the
-control) puts first. Both go through the harness's own judgement against
+control) puts first; the reference is the cell's architecture module's
+(``bench/archs/``). Both go through the harness's own judgement against
 the cell's limits: ``program_correct`` has to come out true and
 ``control_correct`` false. One JSON line per seed. The cell's limits are
 set between the program's largest readings and the control's smallest,
@@ -36,16 +37,17 @@ def readings(cs, system, seed: int, seconds: float, out_dir) -> dict:
 
     if system.weights is None:
         system.engine.params = None
-        system.weights = sut.make_weights(cs.config, seed)
-        system.engine.params = sut.program_params(system.weights, cs.config)
+        system.weights = sut.make_weights(cs.arch, cs.config, seed)
+        system.engine.params = cs.arch.program_params(system.weights,
+                                                      cs.config)
     win = harness.serve_window(cs, system, seed=seed, seconds=seconds,
                                trace=False, t_start=time.perf_counter(),
                                out_dir=out_dir)
     sample = harness.check_sample(win.ctx.requests, seed)
     max_len = int(cs.cell["max_len"])
-    program = harness.logit_gaps(system.weights, cs.config, sample,
+    program = harness.logit_gaps(cs.arch, system.weights, cs.config, sample,
                                  win.prompts, max_len)
-    control = harness.logit_gaps(system.weights, cs.config, sample,
+    control = harness.logit_gaps(cs.arch, system.weights, cs.config, sample,
                                  win.prompts, max_len, precision="int8")
     _, failed, short = harness.request_checks(cs, win.ctx)
     checks = harness.judge(cs, program, failed, short)

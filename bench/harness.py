@@ -37,6 +37,7 @@ class Context:
 
     cell: dict
     config: dict
+    arch: object             # the module bench/archs/<arch>
     end_to_end: tuple        # names of the cell's end-to-end metrics
     slots: int
     pool_pages: int          # usable pages (the scratch page excluded)
@@ -149,15 +150,14 @@ def check_sample(records: list, seed: int) -> list:
     return [cand[0]] + [rest[i] for i in order[:CHECK_REQUESTS - 1]]
 
 
-def logit_gaps(weights, config, sample, prompts, max_len: int, *,
+def logit_gaps(arch, weights, config, sample, prompts, max_len: int, *,
                precision: str = "float32") -> dict:
     """Gaps between the reference's best logit and its logit of the token
     judged, at every served token of ``sample``: the served token, or
     with ``precision="int8"`` the token that the int8 control puts first
-    at that position. Returns the widest gap (``logit_gap``), the mean
-    gap (``mean_logit_gap``) and the number of tokens read."""
-    from bench.reference.dense_gqa import row_stats
-
+    at that position. The reference is the architecture module's
+    ``row_stats``. Returns the widest gap (``logit_gap``), the mean gap
+    (``mean_logit_gap``) and the number of tokens read."""
     gaps = []
     for r in sample:
         served = np.asarray(r["tokens"], np.int32)
@@ -168,10 +168,10 @@ def logit_gaps(weights, config, sample, prompts, max_len: int, *,
         if precision == "float32":
             judged = nxt
         else:
-            _, judged, _ = row_stats(weights, config, seq, precision=precision,
-                                     length=max_len)
-        mx, _, picked = row_stats(weights, config, seq, judged[None],
-                                  length=max_len)
+            _, judged, _ = arch.row_stats(weights, config, seq,
+                                          precision=precision, length=max_len)
+        mx, _, picked = arch.row_stats(weights, config, seq, judged[None],
+                                       length=max_len)
         gaps.append(mx[rows] - picked[0, rows])
     if not gaps:
         return {"logit_gap": None, "mean_logit_gap": None, "tokens": 0}
@@ -187,7 +187,7 @@ def prepare(cs: spec_mod.CellSpec, *, seed: int, t_start: float,
     warm-up (the tests break the timed path with it)."""
     from bench import sut
 
-    system = sut.build_system(cs.config, cs.cell, seed)
+    system = sut.build_system(cs.arch, cs.config, cs.cell, seed)
     if mutate is not None:
         mutate(system)
     log(f"weights made: {time.perf_counter() - t_start:.1f} s")
@@ -253,7 +253,7 @@ def serve_window(cs: spec_mod.CellSpec, system, *, seed: int,
     if driver.w0 is None or driver.w1 is None:
         raise RuntimeError("the window never opened or never closed")
     ctx = Context(
-        cell=cell, config=config,
+        cell=cell, config=config, arch=cs.arch,
         end_to_end=tuple(m["name"] for m in cs.end_to_end),
         slots=engine.batch_size,
         pool_pages=engine.num_pages - 1, page_size=engine.page_size,
@@ -340,7 +340,7 @@ def run_cell(cs: spec_mod.CellSpec, *, seed: int, seconds: float,
     system.engine = None
     gc.collect()
     attempted, failed, short = request_checks(cs, ctx)
-    gaps = logit_gaps(system.weights, cs.config,
+    gaps = logit_gaps(cs.arch, system.weights, cs.config,
                       check_sample(ctx.requests, seed), win.prompts,
                       int(cs.cell["max_len"]))
     checks = judge(cs, gaps, failed, short)

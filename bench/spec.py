@@ -5,7 +5,11 @@ lives in a file of its own under ``bench/``; ``BENCHMARK.json`` at the
 root names them. A later cell, configuration, mix or metric is added as
 new files and entries, with no edit to a file that is already here:
 
-- ``bench/configs/<config>.json``  model sizes, as run
+- ``bench/configs/<config>.json``  model sizes, as run; its ``arch`` names
+  the architecture's module
+- ``bench/archs/<arch>.py``        one architecture's layer equations: the
+  program's ``ArchConfig``, the weight layout and the program's tree, the
+  plain reference and its control, the operations of a step
 - ``bench/traffic/<mix>.json``     traffic parameters for ``traffic/generate``
 - ``bench/traffic/arrivals/<kind>.py``  when a mix's requests are due, how
   many a run offers, and whether the load is open-loop (``arrivals`` in
@@ -15,6 +19,12 @@ new files and entries, with no edit to a file that is already here:
 - ``bench/layer_metrics/<metric>.py``  reader of a per-layer metric; a
   name with a ``.suffix`` (``idle_pct.chat``) uses the reader of its stem
 - ``bench/peaks.json``             published peaks by ``device_kind``
+
+A reader that counts a whole step's work (``mfu_pct``) takes the count
+from the cell's architecture module; a kernel's roofline reader
+(``paged_decode_roofline``, ``paged_prefill_roofline``) counts that one
+kernel's work (``bench/kernels/``), so a new kernel comes with a reader
+of its own.
 """
 
 from __future__ import annotations
@@ -35,6 +45,7 @@ class CellSpec:
     name: str
     chips: int
     config: dict
+    arch: object                   # the module bench/archs/<arch>
     traffic: dict
     arrivals: object               # the module bench/traffic/arrivals/<kind>
     cell: dict
@@ -58,7 +69,8 @@ def _metrics(entries, workload) -> tuple[dict, ...]:
 def load_cell(workload: str, root: pathlib.Path | None = None) -> CellSpec:
     """Read ``BENCHMARK.json`` under ``root`` (the repository root) and the
     files it names for ``workload``. Raises ``KeyError`` for an unknown
-    workload and ``FileNotFoundError`` for a missing file."""
+    workload or a configuration without ``arch``, and
+    ``FileNotFoundError`` for a missing file or architecture module."""
     root = pathlib.Path(root) if root is not None else BENCH_DIR.parent
     bench = _read_json(root / "BENCHMARK.json")
     by_name = {w["name"]: w for w in bench["workloads"]}
@@ -68,12 +80,20 @@ def load_cell(workload: str, root: pathlib.Path | None = None) -> CellSpec:
     w = by_name[workload]
     bench_dir = root / bench["paths"][0]
     configs = {c["name"]: c for c in bench["configs"]}
-    config = _read_json(root / configs[w["config"]]["file"])
+    config_file = root / configs[w["config"]]["file"]
+    config = _read_json(config_file)
+    if "arch" not in config:
+        raise KeyError(f"{config_file} names no architecture (\"arch\")")
+    if not (bench_dir / "archs" / f"{config['arch']}.py").exists():
+        raise FileNotFoundError(
+            f"{config_file} names the architecture {config['arch']!r}, and "
+            f"there is no {bench_dir / 'archs' / config['arch']}.py")
     traffic = _read_json(bench_dir / "traffic" / f"{w['traffic']}.json")
     return CellSpec(
         name=workload,
         chips=int(w["chips"]),
         config=config,
+        arch=load_module(bench_dir, "archs", config["arch"]),
         traffic=traffic,
         arrivals=load_module(bench_dir, "traffic/arrivals",
                              traffic["arrivals"]),

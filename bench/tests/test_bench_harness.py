@@ -61,6 +61,39 @@ def test_new_cell_runs_from_new_files(root, cell, peer):
     assert r["device"]["count"] == 1 and r["device"]["platform"] == "cpu"
 
 
+def test_new_architecture_runs_from_new_files(root):
+    """A configuration of another architecture (the program's MoE block)
+    added as files alone: its module ``bench/archs/smoke_moe.py``, its
+    configuration and a cell. The cell runs through the harness, and the
+    served tokens agree with that module's reference."""
+    assert not (BENCH / "archs/smoke_moe.py").exists()
+    cs = spec.load_cell("smoke-moe.backlog", root)
+    assert cs.arch.__file__ == str(root / "bench/archs/smoke_moe.py")
+    r = _run(root, "smoke-moe.backlog")
+    assert r["correct"], r["checks"]
+    assert r["tokens_checked"] > 0
+    assert set(r["metrics"]) == {m["name"] for m in spec.load_cell(
+        "internlm2-1.8b.reasoning", ROOT).end_to_end}
+    assert r["attempted"] > 0 and r["failed"] == 0
+
+
+@pytest.mark.parametrize("arch", [None, "no_such_arch"])
+def test_load_cell_refuses_a_configuration_without_its_architecture(
+        tmp_path, arch):
+    """A configuration that names no architecture, or one with no module,
+    is an error that names the configuration's file: never a default."""
+    root = make_root(tmp_path, cells=("smoke.chat",))
+    path = root / "bench/configs/smoke.json"
+    config = json.loads(path.read_text())
+    if arch is None:
+        del config["arch"]
+    else:
+        config["arch"] = arch
+    path.write_text(json.dumps(config))
+    with pytest.raises((KeyError, FileNotFoundError), match="smoke.json"):
+        spec.load_cell("smoke.chat", root)
+
+
 def test_new_arrival_kind_runs_from_new_files(root):
     """A kind of arrivals added as a module file (bursts of three) and a
     mix that names it: the cell runs, its requests come in bursts, and the
@@ -78,16 +111,18 @@ def test_new_arrival_kind_runs_from_new_files(root):
     assert r["attempted"] > 0 and r["failed"] == 0
 
 
-def test_control_is_not_correct(root):
-    """The control goes through the harness's own judgement: on the
-    window's checked requests the program passes the cell's limits and the
-    int8 control, put in its place, does not. (The smoke configuration is
-    float32, where the program serves the reference's best token: its
-    limit, 1e-4, lies between its readings, 0, and the control's, 1.2e-3
-    to 5.7e-3 on the backlog over six seeds.)"""
+@pytest.mark.parametrize("cell", ["smoke.backlog", "smoke-moe.backlog"])
+def test_control_is_not_correct(root, cell):
+    """The control goes through the harness's own judgement and the cell's
+    architecture module: on the window's checked requests the program
+    passes the cell's limits and the int8 control, put in its place, does
+    not. (Both smoke configurations are float32, where the program serves
+    the reference's best token: the limit, 1e-4, lies between the
+    program's readings, 0, and the control's on the backlog: 1.2e-3 to
+    8.3e-3 dense over twelve seeds, 1.7e-3 to 2.8e-2 MoE over six.)"""
     from bench import control
 
-    cs = spec.load_cell("smoke.backlog", root)
+    cs = spec.load_cell(cell, root)
     system = harness.prepare(cs, seed=SEED, t_start=time.perf_counter())
     out = control.readings(cs, system, SEED, 1.5, root / ".bench_out")
     assert out["program_correct"], out["checks"]
@@ -186,5 +221,8 @@ def test_benchmark_json_names_every_file():
             spec.load_reader(cs.bench_dir, "end_to_end", m["name"])
         for m in cs.per_layer:
             spec.load_reader(cs.bench_dir, "layer_metrics", m["name"])
+    for c in bench["configs"]:
+        arch = json.loads((ROOT / c["file"]).read_text())["arch"]
+        assert (BENCH / "archs" / f"{arch}.py").exists(), c["file"]
     with pytest.raises(KeyError):
         spec.peaks_for("no such chip")

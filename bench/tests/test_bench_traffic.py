@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from bench import harness, stats
-from bench.tests.smoke_root import DATA
+from bench.tests.smoke_root import DATA, smoke_arch, smoke_config
 from bench.traffic import generate
 from bench.traffic.driver import OpenLoopDriver
 
@@ -74,9 +74,9 @@ def test_residual_life_first_batch():
 def smoke_engine():
     from bench import sut
 
-    config = json.loads((DATA / "smoke_config.json").read_text())
     cell = json.loads((DATA / "smoke_cell.json").read_text())
-    engine = sut.build_system(config, cell, seed=SEED, attn_impl="xla").engine
+    engine = sut.build_system(smoke_arch("smoke"), smoke_config("smoke"),
+                              cell, seed=SEED, attn_impl="xla").engine
     harness._warmup(engine)  # nothing compiles in the tests' windows
     return engine
 
@@ -130,8 +130,8 @@ def test_ttft_counts_from_the_due_time(smoke_engine):
                         for p in planned])
     driver.finish()
     records = harness._records(smoke_engine, planned, driver)
-    ctx = harness.Context(cell={}, config={}, end_to_end=(), slots=2,
-                          pool_pages=16, page_size=16, chunk_size=32,
+    ctx = harness.Context(cell={}, config={}, arch=None, end_to_end=(),
+                          slots=2, pool_pages=16, page_size=16, chunk_size=32,
                           w0=driver.w0,
                           w1=driver.w1, setup_s=0.0, requests=records,
                           steps=[])
