@@ -16,7 +16,8 @@ runs three phases, and any failure exits non-zero:
    at the benchmark's decode shapes (16 slots of 80 pages of 64 rows
    over a pool of 577), with bf16 pools and with int8 pools and their
    per-page scales, against the fp32 oracle over the same (dequantized)
-   pages.
+   pages; and the same kernel in its latent (MLA) mode at the Moonlight
+   cell's decode shapes (32 slots, a pool of 1200 latent pages).
 2. serving: qwen3-1.7b at published widths, random weights from a seed,
    ``attn_impl="pallas"``, through ``ContinuousBatchingEngine``: 16
    requests (prompts of 128-1536 tokens, 32 new tokens each) at batch 8.
@@ -62,6 +63,11 @@ REF_ROWS = 256
 # the paged decode kernel's check: internlm2-1.8b.reasoning's slots,
 # pages a slot, page rows and pool pages
 PAGED_SLOTS, PAGED_MAX_PAGES, PAGED_PAGE, PAGED_POOL = 16, 80, 64, 577
+# the Moonlight cell's latent decode: 32 slots over a pool of 1200 pages,
+# 16 heads; latent rank 512 + rope 64 = 576 values padded to 640
+LATENT_SLOTS, LATENT_POOL, LATENT_HEADS = 32, 1200, 16
+LATENT_DIMS = (576, 512, 640)      # (latent, rank = V width, pool width)
+LATENT_SM_SCALE = (128 + 64) ** -0.5
 # bf16 tolerance of the kernel tests (atol = rtol)
 KERNEL_TOL = 3e-2
 # Pallas against XLA-twin logits: max |difference| over max |logit|. Both
@@ -251,6 +257,70 @@ def paged_kernel_phase(slots=PAGED_SLOTS, max_pages=PAGED_MAX_PAGES,
             f"{float(np.max(np.abs(got - want))):.3e}, tol {KERNEL_TOL}; "
             f"compile {compile_s:.2f}s, first call {run_s:.3f}s "
             f"(host clock)")
+
+
+def latent_kernel_phase(slots=LATENT_SLOTS, max_pages=PAGED_MAX_PAGES,
+                        page=PAGED_PAGE, pool_pages=LATENT_POOL,
+                        heads=LATENT_HEADS, dims=LATENT_DIMS,
+                        interpret=False) -> None:
+    """The paged decode kernel in its latent (MLA) mode at the Moonlight
+    cell's decode shapes: 16 query heads on one latent row per token, zero
+    padded to the pool's width, V its first ``rank`` columns; against the
+    fp32 oracle over the same pages, at the lengths of
+    ``paged_kernel_phase``."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import ops as kops
+    from repro.kernels import ref
+
+    lat, rank, width = dims
+    scale = LATENT_SM_SCALE
+    s_max = max_pages * page
+    blk = kops.decode_pages_per_block(1, page, width, 2, max_pages) * page
+    rng = np.random.default_rng(SEED)
+    lens = rng.integers(1, s_max + 1, size=slots)
+    lens[:6] = (0, 1, page, blk, min(blk + 1, s_max), s_max)
+    lens = jnp.asarray(lens, jnp.int32)
+    table = jnp.asarray(rng.integers(1, pool_pages, size=(slots, max_pages)),
+                        jnp.int32)
+    kq, kp = jax.random.split(jax.random.PRNGKey(SEED))
+    pad = ((0, 0),) * 3 + ((0, width - lat),)
+    q = jnp.pad(jax.random.normal(kq, (slots, heads, lat), jnp.bfloat16),
+                pad[1:])
+    pool = jnp.pad(jax.random.normal(kp, (1, pool_pages, page, lat),
+                                     jnp.bfloat16), pad)
+
+    @jax.jit
+    def oracle(pool):
+        dense = jnp.moveaxis(pool[:, table], 0, 1).reshape(
+            slots, 1, s_max, width).astype(jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            return jax.vmap(lambda q1, k1, n: ref.decode_attention(
+                q1[None], k1[None], k1[None, ..., :rank], n,
+                sm_scale=scale)[0])(q, dense, lens)
+
+    def kernel(q_, pool_, tbl, kv_lens):
+        return kops.paged_decode_attention(q_, pool_, None, tbl, kv_lens,
+                                           sm_scale=scale, v_dim=rank,
+                                           interpret=interpret)
+
+    args = (q, pool, table, lens)
+    t0 = time.perf_counter()
+    compiled = jax.jit(kernel).lower(*args).compile()
+    compile_s = time.perf_counter() - t0
+    _require_kernel(compiled, "paged decode latent")
+    out, run_s = _timed(compiled, *args)
+    live = np.asarray(lens) > 0
+    got = np.asarray(out.astype(jnp.float32))[live]
+    want = np.asarray(oracle(pool))[live]
+    np.testing.assert_allclose(got, want, atol=KERNEL_TOL, rtol=KERNEL_TOL,
+                               err_msg="paged decode latent")
+    log(f"paged decode latent pool: {slots} slots x {max_pages} pages of "
+        f"{page} rows of {lat} (pool width {width}), pool {pool_pages}, "
+        f"{heads} heads, V the first {rank}: max|out-ref|="
+        f"{float(np.max(np.abs(got - want))):.3e}, tol {KERNEL_TOL}; "
+        f"compile {compile_s:.2f}s, first call {run_s:.3f}s (host clock)")
 
 
 # ---------------------------------------------------------------------------
@@ -496,6 +566,7 @@ def main(argv=None) -> int:
     else:
         kernel_phase()
         paged_kernel_phase()
+        latent_kernel_phase()
         log(f"phase kernels done at {time.perf_counter() - t0:.1f}s")
         cfg = dataclasses.replace(arch, attn_impl="pallas")
         params = serving_phase(cfg, requests)

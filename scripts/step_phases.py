@@ -33,6 +33,10 @@ prints one JSON line:
   (the ``kv_pages_live`` stat) over the page table's entries (slots times
   pages a slot), mean over the window's decode steps; null when reducing
   a kept trace, which does not hold the table's shape;
+- ``expert_rows_per_held_expert``: for a model with a held-expert layer
+  (DESIGN.md §12), the (token, held expert) rows of the window's decode
+  steps (the ``expert_rows`` stat) per held expert, per expert layer, per
+  decode step; null when reducing a kept trace, or without such a layer;
 - ``device_offset_ms``: the bounds on the device's clock minus the
   host's that causality gives (each program run starts after its
   ``dispatch`` span starts and ends before its ``host_sync`` span ends).
@@ -228,10 +232,12 @@ def host_idle_s(by_label: dict) -> float:
 
 def reduce(rec: Recording, *, chunk_size: int,
            window_ns: tuple[int, int] | None = None,
-           table_pages: int | None = None) -> dict:
+           table_pages: int | None = None,
+           held_experts: int | None = None) -> dict:
     """The numbers of the module doc over ``window_ns`` (host clock of the
     trace; the span of the device's ops when None). ``table_pages`` is
-    the page table's entries, slots times pages a slot."""
+    the page table's entries, slots times pages a slot; ``held_experts``
+    the held experts times the expert layers."""
     from bench import trace_reduce
 
     if not rec.ops:
@@ -265,6 +271,8 @@ def reduce(rec: Recording, *, chunk_size: int,
             if sp[3]["kind"] in CHUNK_KINDS]
     live = [sp[3]["kv_pages_live"] for sp in steps
             if sp[3]["kind"] == "decode" and "kv_pages_live" in sp[3]]
+    expert_rows = [sp[3]["expert_rows"] for sp in steps
+                   if sp[3]["kind"] == "decode" and "expert_rows" in sp[3]]
     return {
         "window_s": window_s,
         "busy_s": busy_s,
@@ -284,6 +292,9 @@ def reduce(rec: Recording, *, chunk_size: int,
             100.0 * sum(live) / (len(live) * table_pages)
             if live and table_pages else None),
         "device_offset_ms": [b * 1e-6 for b in bounds] if bounds else None,
+        "expert_rows_per_held_expert": (
+            sum(expert_rows) / (len(expert_rows) * held_experts)
+            if expert_rows and held_experts else None),
     }
 
 
@@ -329,10 +340,13 @@ def run_window(args) -> dict:
     # the window on the trace's host clock, as the harness read it
     window = seen["window_ns"]
     eng = system.engine
+    held = (eng.cfg.moe.num_held * eng.cfg.layer_kinds.count("mla_moe")
+            if eng.counts_expert_rows else None)
     out = {"workload": args.workload, "seed": args.seed,
            **reduce(load(kept), chunk_size=ctx.chunk_size,
                     window_ns=window,
-                    table_pages=eng.batch_size * eng.max_pages)}
+                    table_pages=eng.batch_size * eng.max_pages,
+                    held_experts=held)}
     for kind, entries in (("end_to_end", cs.end_to_end),
                           ("layer_metrics", cs.per_layer)):
         out[kind] = {m["name"]: spec.load_reader(cs.bench_dir, kind,

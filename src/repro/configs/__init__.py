@@ -12,7 +12,7 @@ _MODULES = {
     "deepseek-coder-33b": "deepseek_coder_33b",
     "internvl2-2b": "internvl2_2b",
     "recurrentgemma-9b": "recurrentgemma_9b",
-    "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "deepseek-moe-16b": "deepseek_moe_16b",
     "mamba2-130m": "mamba2_130m",
     "whisper-large-v3": "whisper_large_v3",

@@ -178,11 +178,12 @@ def decode_attention(
     return of[:, :group].reshape(b, hq, e)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "v_dim"))
 def paged_decode_attention(
     q: jax.Array,           # (B, Hq, E)
     k_pages: jax.Array,     # (Hkv, P, page, E) — global page pool
-    v_pages: jax.Array,     # (Hkv, P, page, E)
+    v_pages: jax.Array | None,  # (Hkv, P, page, E); None in latent mode
     page_table: jax.Array,  # (B, max_pages) int32
     kv_lens: jax.Array,     # (B,) int32
     *,
@@ -190,8 +191,13 @@ def paged_decode_attention(
     k_scales: jax.Array | None = None,  # (Hkv, P) fp32 per-page scales
     v_scales: jax.Array | None = None,
     interpret: bool | None = None,
+    v_dim: int | None = None,
 ) -> jax.Array:
-    """Single-token decode against a block-table paged KV cache."""
+    """Single-token decode against a block-table paged KV cache.
+
+    Latent mode (``v_dim`` given, ``v_pages`` None; DESIGN.md §12): V is
+    the first ``v_dim`` columns of each K row, so each live page is copied
+    once, and the output is (B, Hq, v_dim)."""
     b, hq, e = q.shape
     hkv, _, page_size, _ = k_pages.shape
     assert hq % hkv == 0
@@ -215,9 +221,9 @@ def paged_decode_attention(
         pages_per_block=decode_pages_per_block(
             hkv, page_size, e, k_pages.dtype.itemsize, page_table.shape[1]),
         sm_scale=sm_scale, k_scales=k_scales, v_scales=v_scales,
-        interpret=interp,
+        interpret=interp, v_dim=v_dim,
     )
-    return of[:, :, :group].reshape(b, hq, e)
+    return of[:, :, :group].reshape(b, hq, of.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))

@@ -38,6 +38,12 @@ the page table — so the page DMA moves 1/2–1/4 the bytes and the
 dequant lands on the VEC stream as a per-page-column multiply of the
 (Hkv, G, T) score tile (K) and of P (V).
 
+Latent mode (``v_dim``; DESIGN.md §12): absorbed latent attention is
+multi-query attention whose one KV head's row is the latent, and whose V
+is the first ``v_dim`` columns of that same row. There is no V pool: each
+live page is copied once into the K block, and P.V reads the block's
+first ``v_dim`` columns; the output is (B, Hkv, G, v_dim).
+
 q pre-grouped to (B, Hkv, G, E) by ops.py; pools are (Hkv, P, page, E);
 scale tables are (Hkv, P) fp32.
 """
@@ -70,12 +76,18 @@ def decode_pages_per_block(hkv: int, page_size: int, e: int, itemsize: int,
 
 def _paged_decode_kernel(
     kvlens_ref, table_ref, *refs, page_size, pages_per_block, sm_scale,
-    quantized,
+    quantized, v_dim,
 ):
     if quantized:
         ks_ref, vs_ref, *refs = refs
-    (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_ref, l_ref,
-     acc_ref, buf_ref) = refs
+    if v_dim is None:
+        (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_ref, l_ref,
+         acc_ref, buf_ref) = refs
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+    else:
+        (q_ref, k_hbm, o_ref, k_buf, sems, m_ref, l_ref, acc_ref,
+         buf_ref) = refs
+        pools = ((k_hbm, k_buf),)
     n_slots, max_pages = table_ref.shape
     hkv = k_buf.shape[1]
     rows = pages_per_block * page_size
@@ -94,15 +106,14 @@ def _paged_decode_kernel(
     def block_copies(s, blk, buf, act):
         """Start (``act="start"``) or wait for the copies of block
         ``blk`` of slot ``s`` into buffer ``buf``: one K and one V copy
-        per live page, every head."""
+        (one latent copy in latent mode) per live page, every head."""
         first, n_live = blk * pages_per_block, live_pages(s)
         for i in range(pages_per_block):
             @pl.when(first + i < n_live)
             def _copy():
                 page = table_ref[s, first + i]
                 dst = pl.ds(i * page_size, page_size)
-                for which, (hbm, vbuf) in enumerate(((k_hbm, k_buf),
-                                                     (v_hbm, v_buf))):
+                for which, (hbm, vbuf) in enumerate(pools):
                     cp = pltpu.make_async_copy(hbm.at[:, page],
                                                vbuf.at[buf, :, dst],
                                                sems.at[which, buf])
@@ -179,7 +190,9 @@ def _paged_decode_kernel(
         # rows past kv_len hold an earlier block's pages: zero them so
         # that P's zeros there cannot meet a non-finite value
         v_rows = jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1) + col0
-        v = jnp.where(v_rows < kv_len, v_buf[buf].astype(jnp.float32), 0.0)
+        live_rows = v_rows < kv_len
+        v_blk = v_buf[buf] if v_dim is None else k_buf[buf][..., :v_dim]
+        v = jnp.where(live_rows, v_blk.astype(jnp.float32), 0.0)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -206,17 +219,20 @@ def paged_decode_attention_flat(
     k_scales: jax.Array | None = None,  # (Hkv, P) fp32 per-page scales
     v_scales: jax.Array | None = None,
     interpret: bool = False,
+    v_dim: int | None = None,           # latent mode: V = K[..., :v_dim]
 ) -> jax.Array:
     b, hkv, g, e = q.shape
     _, _, page_size, _ = k_pages.shape
     quantized = k_scales is not None
     assert (v_scales is None) == (k_scales is None)
+    assert (v_pages is None) == (v_dim is not None)
+    assert not (quantized and v_dim is not None)
     scale = (e**-0.5) if sm_scale is None else sm_scale
 
     kernel = functools.partial(
         _paged_decode_kernel, page_size=page_size,
         pages_per_block=pages_per_block, sm_scale=scale,
-        quantized=quantized,
+        quantized=quantized, v_dim=v_dim,
     )
     scalars = [jnp.asarray(kv_lens, jnp.int32),
                jnp.asarray(page_table, jnp.int32)]
@@ -224,20 +240,21 @@ def paged_decode_attention_flat(
         scalars += [jnp.asarray(k_scales, jnp.float32),
                     jnp.asarray(v_scales, jnp.float32)]
     rows = pages_per_block * page_size
+    dv = e if v_dim is None else v_dim
     q_spec = pl.BlockSpec((1, hkv, g, e), lambda b_, *_: (b_, 0, 0, 0))
+    o_spec = pl.BlockSpec((1, hkv, g, dv), lambda b_, *_: (b_, 0, 0, 0))
+    pools = [k_pages] if v_dim is not None else [k_pages, v_pages]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b,),
-        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((2, hkv, rows, e), k_pages.dtype),
-            pltpu.VMEM((2, hkv, rows, e), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),   # (K/V, buffer)
+        in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=o_spec,
+        scratch_shapes=[pltpu.VMEM((2, hkv, rows, e), p.dtype)
+                        for p in pools] + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),   # (K/V, buffer)
             pltpu.VMEM((hkv, g, 1), jnp.float32),
             pltpu.VMEM((hkv, g, 1), jnp.float32),
-            pltpu.VMEM((hkv, g, e), jnp.float32),
+            pltpu.VMEM((hkv, g, dv), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),       # buffer of the next block
         ],
     )
@@ -252,7 +269,7 @@ def paged_decode_attention_flat(
         kernel,
         name="paged_decode_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, e), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
         interpret=interpret,
         **kwargs,
-    )(*scalars, q, k_pages, v_pages)
+    )(*scalars, q, *pools)

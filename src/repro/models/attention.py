@@ -166,7 +166,8 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, impl="xla",
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, kv_lens, *,
-                           impl="xla", k_scales=None, v_scales=None):
+                           impl="xla", k_scales=None, v_scales=None,
+                           sm_scale=None, v_dim=None):
     """Single-token decode over a block-table paged KV cache.
 
     q: (B, Hq, E); pools: (Hkv, P, page, E); page_table: (B, max_pages)
@@ -180,11 +181,15 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, kv_lens, *,
     applies them exactly where the kernel does — K scales on the score
     columns after the QK^T, V scales folded into P after the normalizer
     sum — so parity holds for quantized caches too.
+
+    Latent mode (``v_dim`` given, ``v_pages`` None): V is the first
+    ``v_dim`` columns of the K pool, and the output (B, Hq, v_dim).
     """
     if impl == "pallas":
         return kops.paged_decode_attention(q, k_pages, v_pages, page_table,
                                            kv_lens, k_scales=k_scales,
-                                           v_scales=v_scales)
+                                           v_scales=v_scales,
+                                           sm_scale=sm_scale, v_dim=v_dim)
     b, hq, e = q.shape
     hkv, _, page, _ = k_pages.shape
     g = hq // hkv
@@ -193,12 +198,15 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, kv_lens, *,
         k_pages, v_pages = cs[0](k_pages), cs[0](v_pages)
     # (Hkv, B, max_pages, page, E) -> (B, Hkv, max_pages*page, E)
     k = jnp.moveaxis(k_pages[:, page_table], 0, 1).reshape(b, hkv, -1, e)
-    v = jnp.moveaxis(v_pages[:, page_table], 0, 1).reshape(b, hkv, -1, e)
+    if v_dim is not None:
+        v = k[..., :v_dim]
+    else:
+        v = jnp.moveaxis(v_pages[:, page_table], 0, 1).reshape(b, hkv, -1, e)
     if cs is not None:
         k, v = cs[0](k, 1), cs[0](v, 1)
     s = k.shape[2]
     qg = q.reshape(b, hkv, g, e)
-    scale = e**-0.5
+    scale = e**-0.5 if sm_scale is None else sm_scale
     sc = jnp.einsum("bkge,bkse->bkgs", qg.astype(jnp.float32),
                     k.astype(jnp.float32)) * scale
     if cs is not None:
@@ -219,7 +227,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, kv_lens, *,
     if v_scales is not None:
         p = p * per_position(v_scales)[:, :, None, :]
     o = jnp.einsum("bkgs,bkse->bkge", p, v.astype(jnp.float32))
-    out = (o / l).reshape(b, hq, e).astype(q.dtype)
+    out = (o / l).reshape(b, hq, v.shape[-1]).astype(q.dtype)
     return out if cs is None else cs[1](out)
 
 

@@ -18,6 +18,58 @@ class MoEConfig:
     num_shared: int = 0          # shared experts (deepseek-moe style)
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
+    # router of the dropless held-expert layer (kind "mla_moe",
+    # models/moe.py ``routed_experts``); the capacity layer above
+    # ignores these
+    scoring: str = "softmax"     # routed_experts takes "sigmoid" only
+    score_bias: bool = False     # per-layer correction bias (E,): it
+    #                              chooses the experts, never weights them
+    norm_topk: bool = True       # renormalise the chosen weights to sum 1
+    #                              (routed_experts takes True only)
+    routed_scale: float = 1.0    # routed_scaling_factor
+    held: tuple[int, int] | None = None  # experts [lo, hi) held; None: all
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        return self.held or (0, self.num_experts)
+
+    @property
+    def num_held(self) -> int:
+        lo, hi = self.held_range
+        return hi - lo
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (deepseek_v3, no q compression): K and V
+    come from one latent of ``kv_lora_rank`` values per token plus one
+    shared rope key of ``qk_rope_head_dim``; the cache holds those
+    ``latent_dim`` values a token and layer."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def pool_dim(self) -> int:
+        """Width of a latent pool row: ``latent_dim`` zero-padded to the
+        TPU's 128-lane tile, which the decode kernel's page copies need
+        (Mosaic refuses a 576-wide slice); the zeros add nothing to a
+        score."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def sm_scale(self) -> float:
+        return self.qk_head_dim ** -0.5
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,6 +102,10 @@ class ArchConfig:
     norm_eps: float = 1e-6
     # moe
     moe: MoEConfig | None = None
+    # latent attention: every layer is kind "mla" (dense MLP of d_ff) or
+    # "mla_moe"; the first ``dense_lead`` layers of a MoE stack are dense
+    mla: MLAConfig | None = None
+    dense_lead: int = 0
     # hybrid (recurrentgemma): repeating unit of block kinds + tail
     block_pattern: tuple[str, ...] | None = None   # e.g. ("rec","rec","attn")
     pattern_tail: tuple[str, ...] = ()
@@ -81,6 +137,10 @@ class ArchConfig:
         """Flat list of block kinds for the decoder stack."""
         if self.family == "ssm":
             return ("ssd",) * self.num_layers
+        if self.mla is not None:
+            body = "mla_moe" if self.moe is not None else "mla"
+            return (("mla",) * self.dense_lead
+                    + (body,) * (self.num_layers - self.dense_lead))
         if self.block_pattern is None:
             return ("attn",) * self.num_layers
         kinds: list[str] = []
@@ -104,13 +164,23 @@ class ArchConfig:
                 p += 2 * e
             return p
 
+        def mla_p():
+            a = self.mla
+            return (d * hq * a.qk_head_dim + d * a.latent_dim
+                    + a.kv_lora_rank
+                    + a.kv_lora_rank * hq * (a.qk_nope_head_dim
+                                             + a.v_head_dim)
+                    + hq * a.v_head_dim * d + d)
+
         def mlp_p():
             return (3 if self.mlp == "swiglu" else 2) * d * self.d_ff + d
 
         def moe_p():
             m = self.moe
             p = d * m.num_experts  # router
-            p += m.num_experts * 3 * d * m.d_expert
+            if m.score_bias:
+                p += m.num_experts
+            p += m.num_held * 3 * d * m.d_expert
             p += m.num_shared * 3 * d * m.d_expert
             return p + d
 
@@ -129,6 +199,10 @@ class ArchConfig:
         for kind in self.layer_kinds:
             if kind == "attn":
                 total += attn_p() + (moe_p() if self.moe else mlp_p())
+            elif kind == "mla":
+                total += mla_p() + mlp_p()
+            elif kind == "mla_moe":
+                total += mla_p() + moe_p()
             elif kind == "rec":
                 total += rec_p() + mlp_p()
             elif kind == "ssd":
@@ -144,9 +218,9 @@ class ArchConfig:
         if self.moe is None:
             return self.param_count()
         m = self.moe
-        full_experts = m.num_experts * 3 * self.d_model * m.d_expert
+        full_experts = m.num_held * 3 * self.d_model * m.d_expert
         active_experts = (m.top_k + m.num_shared) * 3 * self.d_model * m.d_expert
-        n_attn_layers = sum(k == "attn" for k in self.layer_kinds)
+        n_attn_layers = sum(k in ("attn", "mla_moe") for k in self.layer_kinds)
         return (self.param_count()
                 - n_attn_layers * (full_experts
                                    + m.num_shared * 3 * self.d_model * m.d_expert)

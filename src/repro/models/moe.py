@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import ArchConfig, dense_init, split_keys
+from repro.models.common import ArchConfig, dense_init, rms_norm, split_keys
 
 
 def init_moe(key, cfg: ArchConfig):
@@ -181,3 +181,111 @@ def moe_ffn(params, x: jax.Array, cfg: ArchConfig):
             sh["w_down"].astype(dt),
         )
     return combined.reshape(b, s, d), aux.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# dropless held-expert layer (kind "mla_moe"; DESIGN.md §12)
+# ---------------------------------------------------------------------------
+
+def init_routed(key, cfg: ArchConfig):
+    """RMSNorm, a router over all ``num_experts``, the held experts'
+    SwiGLU weights (num_held, ...) and the shared experts."""
+    m = cfg.moe
+    d, f, ne, nh = cfg.d_model, m.d_expert, m.num_experts, m.num_held
+    ks = split_keys(key, ["router", "gate", "up", "down", "sg", "su", "sd"])
+    params = {
+        "norm": jnp.zeros((d,), cfg.param_dtype),
+        "router": dense_init(ks["router"], (d, ne), dtype=cfg.param_dtype),
+        "w_gate": dense_init(ks["gate"], (nh, d, f), in_axis=1,
+                             dtype=cfg.param_dtype),
+        "w_up": dense_init(ks["up"], (nh, d, f), in_axis=1,
+                           dtype=cfg.param_dtype),
+        "w_down": dense_init(ks["down"], (nh, f, d), in_axis=1,
+                             dtype=cfg.param_dtype),
+    }
+    if m.score_bias:
+        params["score_bias"] = jnp.zeros((ne,), cfg.param_dtype)
+    if m.num_shared:
+        fs = f * m.num_shared
+        params["shared"] = {
+            "w_gate": dense_init(ks["sg"], (d, fs), dtype=cfg.param_dtype),
+            "w_up": dense_init(ks["su"], (d, fs), dtype=cfg.param_dtype),
+            "w_down": dense_init(ks["sd"], (fs, d), dtype=cfg.param_dtype),
+        }
+    return params
+
+
+def route(params, h, m):
+    """h: (T, D) -> (expert ids (T, top_k), weights (T, top_k) f32).
+
+    Float32 router logits over every expert, scored by sigmoid; the
+    correction bias (when present) takes part in the choice only; the
+    chosen scores, renormalised over the top_k chosen (held or not),
+    times ``routed_scale``, weight the experts. The one routing that a
+    configuration of this layer uses (deepseek_v3's ``noaux_tc``).
+    """
+    if m.scoring != "sigmoid" or not m.norm_topk:
+        raise NotImplementedError(
+            "routed_experts routes by sigmoid scores renormalised over "
+            f"the chosen experts only (scoring={m.scoring!r}, "
+            f"norm_topk={m.norm_topk})")
+    logits = h.astype(jnp.float32) @ params["router"].astype(jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores
+    if m.score_bias:
+        choice = scores + params["score_bias"].astype(jnp.float32)
+    _, ids = jax.lax.top_k(choice, m.top_k)
+    weights = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return ids, weights * m.routed_scale
+
+
+def routed_experts(params, x: jax.Array, cfg: ArchConfig):
+    """Dropless expert layer that holds experts [lo, hi) of the router's
+    ``num_experts`` (MoEConfig.held) -> (y, rows).
+
+    x: (..., D). The layer normalises x, routes every token over all
+    experts, and computes only the (token, held expert) rows: the rows
+    are sorted by expert, unheld ones last, and each held expert's
+    SwiGLU runs on its own rows in one grouped matmul per matrix
+    (``lax.ragged_dot``, on the TPU the Mosaic kernel
+    ``ragged-dot-none``; rows past the last group are neither computed
+    nor kept), so the work follows the routed rows and no token is
+    dropped. Each token then sums its own rows in choice order, whatever
+    else is in the batch. The absent experts' part is left out (another
+    chip's share); the shared experts apply to every token. ``rows``
+    (int32) counts the rows computed.
+    """
+    with jax.named_scope("moe"):
+        m = cfg.moe
+        lo, _ = m.held_range
+        nh, k = m.num_held, m.top_k
+        shape, dt = x.shape, x.dtype
+        h = rms_norm(x, params["norm"], cfg.norm_eps).reshape(-1, shape[-1])
+        t = h.shape[0]
+        ids, weights = route(params, h, m)
+        local = ids.reshape(-1) - lo
+        held = (local >= 0) & (local < nh)
+        key = jnp.where(held, local, nh)                  # unheld sort last
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.bincount(key, length=nh + 1)[:nh].astype(jnp.int32)
+        rows_in = h[order // k]
+
+        def grouped(x, w):
+            return jax.lax.ragged_dot(x, w.astype(dt), sizes)
+
+        out = grouped(jax.nn.silu(grouped(rows_in, params["w_gate"]))
+                      * grouped(rows_in, params["w_up"]), params["w_down"])
+        # back to (token, choice) order: a permutation, so each token
+        # sums its own top_k rows in choice order
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=order.dtype))
+        out = out[back].reshape(t, k, -1).astype(jnp.float32)
+        keep = held.reshape(t, k)[..., None]
+        y = jnp.sum(jnp.where(keep, out * weights[..., None], 0.0), axis=1)
+        y = y.astype(dt)
+        if m.num_shared:
+            sh = params["shared"]
+            y = y + _swiglu(h, sh["w_gate"].astype(dt), sh["w_up"].astype(dt),
+                            sh["w_down"].astype(dt))
+        return y.reshape(shape), jnp.sum(held, dtype=jnp.int32)

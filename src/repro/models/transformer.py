@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.kernels.common import quantize_q8
 from repro.models import attention as attn_mod
+from repro.models import mla as mla_mod
 from repro.models.common import (
     ArchConfig,
     apply_rope,
@@ -28,7 +29,7 @@ from repro.models.common import (
     sinusoidal_positions,
     split_keys,
 )
-from repro.models.moe import init_moe, moe_ffn
+from repro.models.moe import init_moe, init_routed, moe_ffn, routed_experts
 from repro.models.rglru import init_rglru_block, rglru_block
 from repro.models.ssm import init_ssd_block, ssd_block
 
@@ -42,14 +43,34 @@ def unit_layout(cfg: ArchConfig) -> tuple[tuple[str, ...], int, tuple[str, ...]]
     """(unit_pattern, num_units, tail)."""
     if cfg.family == "ssm":
         pattern: tuple[str, ...] = ("ssd",)
+    elif cfg.mla is not None:
+        pattern = cfg.layer_kinds[-1:]
     elif cfg.block_pattern is not None:
         pattern = cfg.block_pattern
     else:
         pattern = ("attn",)
     tail = cfg.pattern_tail
-    body = cfg.num_layers - len(tail)
+    body = cfg.num_layers - len(tail) - len(lead_kinds(cfg))
     assert body % len(pattern) == 0, (cfg.name, body, pattern)
     return pattern, body // len(pattern), tail
+
+
+def lead_kinds(cfg: ArchConfig) -> tuple[str, ...]:
+    """Kinds of the layers before the scanned units: a latent-attention
+    stack's leading dense layers (``params["lead"]``, stacked)."""
+    return cfg.layer_kinds[:cfg.dense_lead] if cfg.mla is not None else ()
+
+
+def _stacks(cfg: ArchConfig) -> tuple[tuple[str, str], ...]:
+    """(tree key, kind) of the stacked layer groups, in order: the lead
+    layers, then the units (one-block pattern only)."""
+    lead = lead_kinds(cfg)
+    pattern, _, _ = unit_layout(cfg)
+    return ((("lead", lead[0]),) if lead else ()) + (("units", pattern[0]),)
+
+
+def _layer(tree, i):
+    return jax.tree.map(lambda t: t[i], tree)
 
 
 # ---------------------------------------------------------------------------
@@ -413,6 +434,10 @@ def init_block(key, kind: str, cfg: ArchConfig, *, with_cross=False):
     if kind == "rec":
         return {"rec": init_rglru_block(ks["main"], cfg),
                 "ffn": init_mlp(ks["ffn"], cfg)}
+    if kind in ("mla", "mla_moe"):
+        return {"attn": mla_mod.init_mla(ks["main"], cfg),
+                "ffn": (init_routed if kind == "mla_moe" else init_mlp)(
+                    ks["ffn"], cfg)}
     assert kind == "attn"
     p = {"attn": init_attn(ks["main"], cfg)}
     if with_cross:
@@ -434,6 +459,11 @@ def make_cache_block(kind: str, cfg: ArchConfig, batch: int, max_len: int,
     memories stay at the compute dtype (written once, read every step).
     """
     e = cfg.hd
+    if kind in ("mla", "mla_moe"):
+        if kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8:
+            raise NotImplementedError("int8 latent cache (MLA)")
+        return {"lat": jnp.zeros((batch, max_len, cfg.mla.latent_dim),
+                                 dtype)}
     if kind == "attn":
         c = min(max_len, cfg.window) if cfg.window else max_len
         kv_dt = kv_dtype or dtype
@@ -476,6 +506,10 @@ def apply_block_train(params, kind, x, cfg: ArchConfig, positions):
         y, _ = rglru_block(params["rec"], x, cfg)
         x = x + y
         return x + mlp(params["ffn"], x, cfg), aux
+    if kind in ("mla", "mla_moe"):
+        y, _ = mla_mod.mla_block(params["attn"], x, cfg, positions=positions)
+        x = x + y
+        return x + _mla_ffn(params["ffn"], kind, x, cfg)[0], aux
     window = cfg.window if cfg.block_pattern is not None else None
     y, _ = attn_block(params["attn"], x, cfg, positions=positions,
                       window=window, causal=cfg.causal)
@@ -489,8 +523,21 @@ def apply_block_train(params, kind, x, cfg: ArchConfig, positions):
     return x + y, aux
 
 
+def _mla_ffn(params, kind, x, cfg: ArchConfig):
+    """The MLP half of a latent-attention layer -> (y, expert rows or
+    None): a dense SwiGLU, or the dropless held-expert layer."""
+    if kind == "mla_moe":
+        return routed_experts(params, x, cfg)
+    return mlp(params, x, cfg), None
+
+
 def apply_block_decode(params, kind, x, cfg: ArchConfig, cache, pos):
     """One-token step. Returns (x, new_cache_block)."""
+    if kind in ("mla", "mla_moe"):
+        y, lat = mla_mod.mla_decode(params["attn"], x, cfg,
+                                    cache_lat=cache["lat"], pos=pos)
+        x = x + y
+        return x + _mla_ffn(params["ffn"], kind, x, cfg)[0], {"lat": lat}
     if kind == "ssd":
         y, (conv, state) = ssd_block(
             params["ssd"], x, cfg, conv_state=cache["conv"],
@@ -551,6 +598,11 @@ def init(rng, cfg: ArchConfig):
 
     unit_keys = jax.random.split(ks["units"], num_units)
     params["units"] = jax.vmap(init_unit)(unit_keys)
+    lead = lead_kinds(cfg)
+    if lead:  # a latent-attention stack has no tail: its key is free
+        params["lead"] = jax.vmap(
+            lambda key: {"b0": init_block(key, lead[0], cfg)})(
+                jax.random.split(ks["tail"], len(lead)))
     if tail:
         tkeys = jax.random.split(ks["tail"], len(tail))
         params["tail"] = {
@@ -629,6 +681,10 @@ def forward(params, tokens, cfg: ArchConfig, *, frontend_embeds=None,
     mem_kv = None
     if encoder_out is not None:
         mem_kv = encoder_out  # projected per block below
+
+    for i, kind in enumerate(lead_kinds(cfg)):
+        x, _ = apply_block_train(_layer(params["lead"]["b0"], i), kind, x,
+                                 cfg, positions)
 
     def unit_body(carry, p_unit):
         x, aux = carry
@@ -715,6 +771,12 @@ def make_cache(cfg: ArchConfig, batch: int, max_len: int, *, mem_len=0,
             one_unit(0),
         )
     }
+    lead = lead_kinds(cfg)
+    if lead:
+        blk = make_cache_block(lead[0], cfg, batch, max_len,
+                               cfg.compute_dtype, kv_dtype=kv_dtype)
+        cache["lead"] = {"b0": jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (len(lead),) + x.shape), blk)}
     if tail:
         cache["tail"] = {
             f"t{j}": make_cache_block(
@@ -729,8 +791,8 @@ def make_cache(cfg: ArchConfig, batch: int, max_len: int, *, mem_len=0,
 
 def _check_paged_support(cfg: ArchConfig):
     pattern, _, tail = unit_layout(cfg)
-    if (pattern != ("attn",) or tail or cfg.window is not None
-            or cfg.encoder_layers or not cfg.rope):
+    if (pattern not in (("attn",), ("mla",), ("mla_moe",)) or tail
+            or cfg.window is not None or cfg.encoder_layers or not cfg.rope):
         raise NotImplementedError(
             "paged cache layout supports pure-attention rope decoder "
             f"stacks only (got {cfg.name})"
@@ -746,10 +808,22 @@ def make_paged_cache(cfg: ArchConfig, num_pages: int, page_size: int,
     physical slot in all pools), so it travels as a decode-step argument
     instead. ``kv_dtype=jnp.int8`` adds the per-page fp32 scales
     side-table (Hkv, P) for K and V (DESIGN.md §5).
+
+    A latent-attention stack holds one latent pool (1, P, page,
+    pool_dim) per layer (DESIGN.md §12), the lead layers' under
+    ``"lead"``; every leaf keeps the page axis at axis 2.
     """
     _check_paged_support(cfg)
     _, num_units, _ = unit_layout(cfg)
     kv_dt = kv_dtype or cfg.compute_dtype
+    if cfg.mla is not None:
+        if jnp.dtype(kv_dt) == jnp.int8:
+            raise NotImplementedError("int8 latent pages (MLA)")
+        z = jnp.zeros((1, num_pages, page_size, cfg.mla.pool_dim), kv_dt)
+        return {name: {"b0": {"lat": jnp.broadcast_to(
+                    z, (len(lead_kinds(cfg)) if name == "lead"
+                        else num_units,) + z.shape)}}
+                for name, _ in _stacks(cfg)}
     z = jnp.zeros((cfg.num_kv_heads, num_pages, page_size, cfg.hd), kv_dt)
     blk = {"k": z, "v": z}
     if jnp.dtype(kv_dt) == jnp.int8:
@@ -798,31 +872,62 @@ def write_prefill_pages(cfg: ArchConfig, cache, dense_cache, page_ids):
     return dict(cache, units=units)
 
 
+def _paged_layers(params, cfg: ArchConfig, cache, x, layer):
+    """Scan each stacked layer group (``_stacks``) of a paged step:
+    ``layer(kind, p, c, x) -> (x, c, expert rows or None)``. Returns (x,
+    the new cache, the expert rows summed over layers)."""
+    new_cache, rows = {}, jnp.int32(0)
+    for name, kind in _stacks(cfg):
+        if kind == "mla_moe":  # the rows ride the carry, summed
+            def body(carry, xs, kind=kind):
+                x, c, r = layer(kind, xs[0]["b0"], xs[1]["b0"], carry[0])
+                return (x, carry[1] + r), {"b0": c}
+            (x, rows), new_cache[name] = jax.lax.scan(
+                body, (x, rows), (params[name], cache[name]))
+        else:
+            def body(x, xs, kind=kind):
+                x, c, _ = layer(kind, xs[0]["b0"], xs[1]["b0"], x)
+                return x, {"b0": c}
+            x, new_cache[name] = jax.lax.scan(body, x, (params[name],
+                                                        cache[name]))
+    return x, new_cache, rows
+
+
+def _dense_ffn(p, x, cfg: ArchConfig):
+    if cfg.moe is not None:
+        return moe_ffn(p, x, cfg)[0]
+    return mlp(p, x, cfg)
+
+
 def paged_decode_step(params, cfg: ArchConfig, token, cache, page_table,
-                      positions):
+                      positions, *, with_rows=False):
     """token: (B, 1) int32; page_table: (B, max_pages) int32; positions:
-    (B,) int32 per-sequence -> (logits (B, 1, V), cache)."""
+    (B,) int32 per-sequence -> (logits (B, 1, V), cache), and with
+    ``with_rows`` the step's (token, held expert) rows over all layers
+    (int32) last."""
     _check_paged_support(cfg)
     x = _embed(params, token, cfg)
 
-    def unit_body(x, xs):
-        p_unit, c_unit = xs
-        p, c = p_unit["b0"], c_unit["b0"]
-        y, pool_updates = attn_paged_decode(
-            p["attn"], x, cfg, k_pages=c["k"], v_pages=c["v"],
-            page_table=page_table, positions=positions,
-            k_scales=c.get("k_scale"), v_scales=c.get("v_scale"),
-        )
+    def layer(kind, p, c, x):
+        if kind == "attn":
+            y, pool_updates = attn_paged_decode(
+                p["attn"], x, cfg, k_pages=c["k"], v_pages=c["v"],
+                page_table=page_table, positions=positions,
+                k_scales=c.get("k_scale"), v_scales=c.get("v_scale"),
+            )
+            x = x + y
+            return x + _dense_ffn(p["ffn"], x, cfg), dict(
+                c, **pool_updates), None
+        y, pool = mla_mod.mla_paged_decode(
+            p["attn"], x, cfg, pool=c["lat"], page_table=page_table,
+            positions=positions)
         x = x + y
-        if cfg.moe is not None:
-            y, _ = moe_ffn(p["ffn"], x, cfg)
-        else:
-            y = mlp(p["ffn"], x, cfg)
-        return x + y, {"b0": dict(c, **pool_updates)}
+        y, rows = _mla_ffn(p["ffn"], kind, x, cfg)
+        return x + y, {"lat": pool}, rows
 
-    x, new_units = jax.lax.scan(unit_body, x,
-                                (params["units"], cache["units"]))
-    return _unembed(params, x, cfg), {"units": new_units}
+    x, new_cache, rows = _paged_layers(params, cfg, cache, x, layer)
+    out = (_unembed(params, x, cfg), new_cache)
+    return out + (rows,) if with_rows else out
 
 
 def paged_verify_step(params, cfg: ArchConfig, tokens, cache, page_table,
@@ -842,6 +947,9 @@ def paged_verify_step(params, cfg: ArchConfig, tokens, cache, page_table,
     op-equivalent to ``paged_decode_step``.
     """
     _check_paged_support(cfg)
+    if cfg.mla is not None:
+        raise NotImplementedError("speculative verify with latent "
+                                  "attention (MLA)")
     x = _embed(params, tokens, cfg)
 
     def unit_body(x, xs):
@@ -865,7 +973,7 @@ def paged_verify_step(params, cfg: ArchConfig, tokens, cache, page_table,
 
 
 def prefill_chunk(params, cfg: ArchConfig, tokens, cache, page_table,
-                  chunk_page_ids, q_offset, chunk_len):
+                  chunk_page_ids, q_offset, chunk_len, *, with_rows=False):
     """One prompt chunk of chunked paged prefill (DESIGN.md §6).
 
     tokens: (1, chunk) int32 — chunk rows at absolute positions
@@ -876,39 +984,51 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, cache, page_table,
     returns ``(last_logits (1, V), cache)`` where ``last_logits`` is the
     chunk's last LIVE row — on the final chunk, the admitted request's
     first token, with no dense batch-1 cache and no copy-on-admit
-    scatter anywhere on the path.
+    scatter anywhere on the path. ``with_rows`` appends the chunk's
+    (token, held expert) rows, pad rows included.
     """
     _check_paged_support(cfg)
     x = _embed(params, tokens, cfg)
     kv_len = q_offset + chunk_len
 
-    def unit_body(x, xs):
-        p_unit, c_unit = xs
-        p, c = p_unit["b0"], c_unit["b0"]
-        y, pool_updates = attn_paged_prefill(
-            p["attn"], x, cfg, k_pages=c["k"], v_pages=c["v"],
-            page_table=page_table, chunk_page_ids=chunk_page_ids,
-            q_offset=q_offset, kv_len=kv_len,
-            k_scales=c.get("k_scale"), v_scales=c.get("v_scale"),
-        )
+    def layer(kind, p, c, x):
+        if kind == "attn":
+            y, pool_updates = attn_paged_prefill(
+                p["attn"], x, cfg, k_pages=c["k"], v_pages=c["v"],
+                page_table=page_table, chunk_page_ids=chunk_page_ids,
+                q_offset=q_offset, kv_len=kv_len,
+                k_scales=c.get("k_scale"), v_scales=c.get("v_scale"),
+            )
+            x = x + y
+            return x + _dense_ffn(p["ffn"], x, cfg), dict(
+                c, **pool_updates), None
+        y, pool = mla_mod.mla_paged_prefill(
+            p["attn"], x, cfg, pool=c["lat"], page_table=page_table,
+            chunk_page_ids=chunk_page_ids, q_offset=q_offset, kv_len=kv_len)
         x = x + y
-        if cfg.moe is not None:
-            y, _ = moe_ffn(p["ffn"], x, cfg)
-        else:
-            y = mlp(p["ffn"], x, cfg)
-        return x + y, {"b0": dict(c, **pool_updates)}
+        y, rows = _mla_ffn(p["ffn"], kind, x, cfg)
+        return x + y, {"lat": pool}, rows
 
-    x, new_units = jax.lax.scan(unit_body, x,
-                                (params["units"], cache["units"]))
+    x, new_cache, rows = _paged_layers(params, cfg, cache, x, layer)
     last = jax.lax.dynamic_slice_in_dim(x, chunk_len - 1, 1, axis=1)
     logits = _unembed(params, last, cfg)
-    return logits[:, 0], {"units": new_units}
+    out = (logits[:, 0], new_cache)
+    return out + (rows,) if with_rows else out
 
 
 def decode_step(params, cfg: ArchConfig, token, cache, pos):
     """token: (B, 1) int32; pos: scalar int32 -> (logits (B, 1, V), cache)."""
     pattern, num_units, tail = unit_layout(cfg)
     x = _embed(params, token, cfg, positions=jnp.asarray(pos)[None])
+    new_cache: dict[str, Any] = {}
+    lead = []
+    for i, kind in enumerate(lead_kinds(cfg)):
+        x, c = apply_block_decode(_layer(params["lead"]["b0"], i), kind, x,
+                                  cfg, _layer(cache["lead"]["b0"], i), pos)
+        lead.append(c)
+    if lead:
+        new_cache["lead"] = {"b0": jax.tree.map(lambda *a: jnp.stack(a),
+                                                *lead)}
 
     def unit_body(x, xs):
         p_unit, c_unit = xs
@@ -921,7 +1041,7 @@ def decode_step(params, cfg: ArchConfig, token, cache, pos):
 
     x, new_units = jax.lax.scan(unit_body, x,
                                 (params["units"], cache["units"]))
-    new_cache: dict[str, Any] = {"units": new_units}
+    new_cache["units"] = new_units
     if tail:
         new_cache["tail"] = {}
         for j, kind in enumerate(tail):
@@ -998,6 +1118,13 @@ def prefill(params, cfg: ArchConfig, tokens, max_len, *,
     def fill_block(p, kind, x, cache_blk):
         if kind == "attn":
             return fill_attn(p, x, cache_blk)
+        if kind in ("mla", "mla_moe"):
+            y, lat = mla_mod.mla_block(p["attn"], x, cfg, positions=positions)
+            x = x + y
+            lat = jax.lax.dynamic_update_slice_in_dim(
+                cache_blk["lat"], lat.astype(cache_blk["lat"].dtype), 0,
+                axis=1)
+            return x + _mla_ffn(p["ffn"], kind, x, cfg)[0], {"lat": lat}
         if kind == "rec":
             y, (conv, rnn) = rglru_block(p["rec"], x, cfg)
             x = x + y
@@ -1016,9 +1143,18 @@ def prefill(params, cfg: ArchConfig, tokens, max_len, *,
     cache = make_cache(cfg, b, max_len,
                        mem_len=mem.shape[1] if mem is not None else 0,
                        kv_dtype=kv_dtype)
+    new_cache: dict[str, Any] = {}
+    lead = []
+    for i, kind in enumerate(lead_kinds(cfg)):
+        x, c = fill_block(_layer(params["lead"]["b0"], i), kind, x,
+                          _layer(cache["lead"]["b0"], i))
+        lead.append(c)
+    if lead:
+        new_cache["lead"] = {"b0": jax.tree.map(lambda *a: jnp.stack(a),
+                                                *lead)}
     x, new_units = jax.lax.scan(unit_body, x,
                                 (params["units"], cache["units"]))
-    new_cache: dict[str, Any] = {"units": new_units}
+    new_cache["units"] = new_units
     if tail:
         new_cache["tail"] = {}
         for j, kind in enumerate(tail):

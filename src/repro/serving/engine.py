@@ -393,6 +393,17 @@ class ContinuousBatchingEngine:
         self.model = model
         self.params = params
         self.cfg = model.cfg
+        if self.cfg.mla is not None:
+            # latent attention (DESIGN.md §12) runs plain bf16 paged decode
+            # and chunked prefill only
+            for feature, on in (
+                    ("speculative verify", spec_depth is not None),
+                    ("int8 latent pages", kv_dtype is not None
+                     and jnp.dtype(kv_dtype) == jnp.int8),
+                    ("prefix reuse", prefix_cache)):
+                if on:
+                    raise NotImplementedError(
+                        f"{feature} with latent attention (MLA)")
         self.max_len = max_len
         self.batch_size = batch_size
         self.page_size = page_size
@@ -500,6 +511,15 @@ class ContinuousBatchingEngine:
         # sync for the NaN guard would cost as much as the guard saves).
         B_, MP = batch_size, self.max_pages
         CS, CP = self.chunk_size, self.chunk_pages
+        # a held-expert layer's (token, held expert) rows of the step ride
+        # the same transfer, as its last value (``expert_rows``)
+        self.counts_expert_rows = "mla_moe" in self.cfg.layer_kinds
+        rows_kw = {"with_rows": True} if self.counts_expert_rows else {}
+
+        def with_rows(out, *rows):
+            if not self.counts_expert_rows:
+                return out
+            return jnp.concatenate([out, sum(rows)[None]])
 
         def unpack_hs(hs):
             return (hs[:B_][:, None], hs[2 * B_:].reshape(B_, MP),
@@ -511,13 +531,13 @@ class ContinuousBatchingEngine:
 
         def decode_step(p, c, hs):
             t, table, pos = unpack_hs(hs)
-            logits, c = model.paged_decode_step(p, model.cfg, t, c, table,
-                                                pos)
+            logits, c, *rows = model.paged_decode_step(
+                p, model.cfg, t, c, table, pos, **rows_kw)
             last = logits[:, -1]
-            return jnp.concatenate([
+            return with_rows(jnp.concatenate([
                 jnp.argmax(last, axis=-1).astype(jnp.int32),
                 _finite_rows(last).astype(jnp.int32),
-            ]), c
+            ]), *rows), c
 
         def chunk_step(p, c, hs, ch):
             # one mixed step: the prompt chunk and ALL decode slots in a
@@ -525,32 +545,32 @@ class ContinuousBatchingEngine:
             # flags) land in one host transfer
             t, table, pos = unpack_hs(hs)
             ctokens, cpages, seq_table, q_offset, chunk_len = unpack_ch(ch)
-            first_logits, c = model.prefill_chunk(
+            first_logits, c, *chunk_rows = model.prefill_chunk(
                 p, model.cfg, ctokens, c, seq_table, cpages, q_offset,
-                chunk_len,
+                chunk_len, **rows_kw,
             )
-            logits, c = model.paged_decode_step(p, model.cfg, t, c, table,
-                                                pos)
+            logits, c, *rows = model.paged_decode_step(
+                p, model.cfg, t, c, table, pos, **rows_kw)
             last = logits[:, -1]
-            return jnp.concatenate([
+            return with_rows(jnp.concatenate([
                 jnp.argmax(last, axis=-1).astype(jnp.int32),
                 jnp.argmax(first_logits, axis=-1).astype(jnp.int32),
                 _finite_rows(last).astype(jnp.int32),
                 _finite_rows(first_logits).astype(jnp.int32),
-            ]), c
+            ]), *chunk_rows, *rows), c
 
         def chunk_only(p, c, ch):
             # no live decode slots: don't pay a dead full-batch decode
             # pass just to move the prefill along
             ctokens, cpages, seq_table, q_offset, chunk_len = unpack_ch(ch)
-            first_logits, c = model.prefill_chunk(
+            first_logits, c, *rows = model.prefill_chunk(
                 p, model.cfg, ctokens, c, seq_table, cpages, q_offset,
-                chunk_len,
+                chunk_len, **rows_kw,
             )
-            return jnp.concatenate([
+            return with_rows(jnp.concatenate([
                 jnp.argmax(first_logits, axis=-1).astype(jnp.int32),
                 _finite_rows(first_logits).astype(jnp.int32),
-            ]), c
+            ]), *rows), c
 
         self._decode = jax.jit(decode_step)
         self._chunk_step = jax.jit(chunk_step)
@@ -597,6 +617,9 @@ class ContinuousBatchingEngine:
 
     def kv_bytes_per_page(self) -> int:
         cfg = self.cfg
+        if cfg.mla is not None:  # one latent row a token and layer
+            return (cfg.num_layers * self.page_size * cfg.mla.pool_dim
+                    * self.kv_dtype.itemsize)
         return page_footprint_bytes(
             num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
             page_size=self.page_size, head_dim=cfg.hd,
@@ -1244,6 +1267,11 @@ class ContinuousBatchingEngine:
                 with tr.span("host_sync", track="engine"):
                     raw = np.asarray(packed)
                 now = time.perf_counter()
+                if self.counts_expert_rows:
+                    raw, rows = raw[:-1], int(raw[-1])
+                    if tracing:
+                        step_args["expert_rows"] = rows
+                        tr.counter("moe.held_rows", rows, track="moe")
                 m_sync.observe(now - t_disp)
                 m_step_kind[kind].observe(now - t_step0)
                 self._observe_step(clen, len(active))

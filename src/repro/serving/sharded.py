@@ -53,6 +53,9 @@ class ShardedContinuousBatchingEngine(ContinuousBatchingEngine):
 
     def __init__(self, model, params, *, shard: int | str = "auto",
                  mesh_axis: str = "model", **kw):
+        if model.cfg.mla is not None:
+            raise NotImplementedError("the KV-head-sharded engine with "
+                                      "latent attention (MLA)")
         super().__init__(model, params, **kw)
         cfg = self.cfg
         ndev = len(jax.devices())

@@ -113,7 +113,7 @@ def test_full_config_is_exact(arch_id):
         "deepseek-coder-33b": (62, 7168, 56, 8, 19200, 32256),
         "internvl2-2b": (24, 2048, 16, 8, 8192, 92553),
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
-        "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
         "deepseek-moe-16b": (28, 2048, 16, 16, 1408, 102400),
         "mamba2-130m": (24, 768, 12, 12, 0, 50280),
         "whisper-large-v3": (32, 1280, 20, 20, 5120, 51866),

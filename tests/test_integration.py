@@ -85,7 +85,7 @@ def test_moe_capacity_drops_tokens():
     from repro.models.moe import init_moe, moe_ffn
 
     cfg = dataclasses.replace(
-        get_smoke("moonshot-v1-16b-a3b"),
+        get_smoke("deepseek-moe-16b"),
         moe=MoEConfig(num_experts=4, top_k=2, d_expert=16, num_shared=0,
                       capacity_factor=0.25),
     )

@@ -20,7 +20,7 @@ def test_choose_policy_mapping():
     assert choose_policy(get_arch("deepseek-coder-33b"),
                          SHAPES["train_4k"]) == "fsdp"
     # MoE training keeps EP over 'model'
-    assert choose_policy(get_arch("moonshot-v1-16b-a3b"),
+    assert choose_policy(get_arch("moonlight-16b-a3b"),
                          SHAPES["train_4k"]) == "tp_sp"
     # small-model prefill replicates weights
     assert choose_policy(get_arch("qwen3-1.7b"),

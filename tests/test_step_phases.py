@@ -142,6 +142,21 @@ def test_reduce_reads_decode_live_pages(sp):
         "decode_live_page_pct"] is None
 
 
+def test_reduce_reads_expert_rows_per_held_expert(sp):
+    rec = _recording(sp, STEPS)
+    steps = [e[3] for e in rec.spans if e[0] == "step"]
+    # hand-made expert_rows: both decode steps, and a chunk+decode step
+    # that the mean leaves out
+    for stats, rows in zip(steps[1:], (9999, 624, 520)):
+        stats["expert_rows"] = rows
+    out = sp.reduce(rec, chunk_size=32, window_ns=(0, 36 * MS),
+                    held_experts=8 * 26)
+    assert out["expert_rows_per_held_expert"] == pytest.approx(
+        (624 + 520) / 2 / (8 * 26))
+    assert sp.reduce(rec, chunk_size=32, window_ns=(0, 36 * MS))[
+        "expert_rows_per_held_expert"] is None
+
+
 @pytest.fixture(scope="module")
 def recording(sp):
     return sp.load(TRACE)

@@ -177,3 +177,40 @@ def test_qwen3_paged_decode_step_compiles(compile_for_chip):
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert used < V5E_HBM_BYTES, used
+
+
+def test_latent_decode_kernel_compiles(compile_for_chip):
+    """The latent (MLA) mode at the Moonlight cell's decode shapes: 32
+    slots of 80 pages of 64 rows over a pool of 1200, 16 heads on one
+    latent row zero-padded from 576 to 640 (Mosaic refuses a 576-wide
+    page copy), V its first 512 columns."""
+    compiled = compile_for_chip(
+        lambda q, pool, table, lens: kops.paged_decode_attention(
+            q, pool, None, table, lens, sm_scale=192 ** -0.5, v_dim=512),
+        _sds((32, 16, 640)), _sds((1, 1200, 64, 640)),
+        _sds((32, 80), jnp.int32), _sds((32,), jnp.int32))
+    assert "paged_decode_attention" in _custom_calls(compiled)
+
+
+@pytest.mark.parametrize("tokens", [32, 1024])
+def test_held_expert_layer_compiles(compile_for_chip, tokens):
+    """Moonlight's expert layer with 8 of 64 experts held, at a decode
+    batch and a prompt chunk: the grouped matmuls are one Mosaic kernel
+    family, ``ragged-dot-none``, the name the benchmark's reader looks
+    for."""
+    from repro.models.common import MoEConfig
+    from repro.models.moe import init_routed, routed_experts
+
+    cfg = dataclasses.replace(
+        get_arch("moonlight-16b-a3b"), param_dtype=jnp.bfloat16,
+        moe=MoEConfig(num_experts=64, top_k=6, d_expert=1408, num_shared=2,
+                      scoring="sigmoid", score_bias=True,
+                      routed_scale=2.446, held=(0, 8)))
+    params = jax.eval_shape(lambda: init_routed(jax.random.PRNGKey(0), cfg))
+    flat, tree = jax.tree.flatten(params)
+
+    def layer(x, *leaves):
+        return routed_experts(jax.tree.unflatten(tree, leaves), x, cfg)
+
+    compiled = compile_for_chip(layer, _sds((tokens, 1, 2048)), *flat)
+    assert "ragged-dot-none" in _custom_calls(compiled)
